@@ -5,6 +5,8 @@ import json
 import os
 import time
 
+import jax
+
 ARTIFACTS = os.path.join(os.path.dirname(__file__), "..", "experiments")
 
 
@@ -17,20 +19,12 @@ def art_dir(name: str) -> str:
 def time_us(fn, *args, warmup: int = 2, iters: int = 10) -> float:
     for _ in range(warmup):
         r = fn(*args)
-    _block(r)
+    jax.block_until_ready(r)
     t0 = time.perf_counter()
     for _ in range(iters):
         r = fn(*args)
-    _block(r)
+    jax.block_until_ready(r)
     return (time.perf_counter() - t0) / iters * 1e6
-
-
-def _block(r):
-    try:
-        import jax
-        jax.block_until_ready(r)
-    except Exception:
-        pass
 
 
 def interleaved_best_us(fns: dict, *, iters: int, rounds: int) -> dict:
@@ -39,7 +33,6 @@ def interleaved_best_us(fns: dict, *, iters: int, rounds: int) -> dict:
     alike (ratios stay meaningful on a loaded box). Compiles + warms each
     callable once before timing. fns: name -> nullary callable returning
     a jax value (blocked on per window)."""
-    import jax
     for fn in fns.values():                    # compile + warm
         jax.block_until_ready(fn())
     best = {name: float("inf") for name in fns}

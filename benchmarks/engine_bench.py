@@ -204,7 +204,7 @@ def _active_compute(rounds: int, repeats: int) -> dict:
 
     The m=N row runs the masked program (auto: no cut to exploit) and
     doubles as the reference denominator."""
-    from repro.launch.dryrun import cost_dict
+    from repro.launch.hlo_cost import cost_dict
 
     n, per = 32, 100
     (xtr, ytr), test = mnist_like(n_train=n * per, n_test=500, seed=0)

@@ -14,6 +14,8 @@ import argparse
 import sys
 import traceback
 
+from repro.launch.compile_cache import use_compile_cache
+
 
 def main() -> None:
     ap = argparse.ArgumentParser()
@@ -22,6 +24,7 @@ def main() -> None:
                     help="comma-separated module names (e.g. fig3_mnist)")
     args = ap.parse_args()
     fast = not args.slow
+    use_compile_cache()
 
     from benchmarks import (ablation, comm_table, engine_bench,
                             fig2_clustering, fig3_mnist, fig5_cifar,

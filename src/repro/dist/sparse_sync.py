@@ -25,7 +25,6 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core.sparsify import bucket_budgets
@@ -361,14 +360,16 @@ def make_manual_sync(mesh, specs, shapes, *, method: str = "rage_k",
                  + ({"wire_bytes_per_shard": P(), "active_shards": P(),
                      "wire_bytes_total": P(),
                      "quarantined_shards": P()},))
-    mapped = shard_map(_make_exchange(False), mesh=mesh,
-                       in_specs=in_specs, out_specs=out_specs,
-                       check_rep=False)
+    # check_vma off: each shard's gradient is its own (local microbatch)
+    # although its spec replicates it over the data axes
+    mapped = jax.shard_map(_make_exchange(False), mesh=mesh,
+                           in_specs=in_specs, out_specs=out_specs,
+                           check_vma=False)
     # participation-masked variant: the (n_data,) active mask rides
     # replicated ahead of the leaves
-    mapped_act = shard_map(_make_exchange(True), mesh=mesh,
-                           in_specs=(P(None),) + in_specs,
-                           out_specs=out_specs, check_rep=False)
+    mapped_act = jax.shard_map(_make_exchange(True), mesh=mesh,
+                               in_specs=(P(None),) + in_specs,
+                               out_specs=out_specs, check_vma=False)
 
     def sync(grads, ages, active=None):
         g_leaves = jax.tree_util.tree_leaves(grads)

@@ -30,8 +30,8 @@ def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref):
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     q = q_ref[0].astype(jnp.float32)              # (rep, D)
-    k = k_ref[:, 0, :].astype(jnp.float32)        # (BLOCK_S, D)
-    v = v_ref[:, 0, :].astype(jnp.float32)        # (BLOCK_S, D)
+    k = k_ref[0].astype(jnp.float32)              # (BLOCK_S, D)
+    v = v_ref[0].astype(jnp.float32)              # (BLOCK_S, D)
     scale = q.shape[-1] ** -0.5
     s = jnp.dot(q * scale, k.T,
                 preferred_element_type=jnp.float32)  # (rep, BLOCK_S)
@@ -58,7 +58,7 @@ def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref):
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def decode_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
-                     cache_len: jnp.ndarray, *, interpret: bool = True):
+                     cache_len: jnp.ndarray, *, interpret: bool = False):
     """q: (H, D); k/v: (S, G, D) with H % G == 0, S % BLOCK_S == 0;
     cache_len: (1,) int32 number of valid cache entries. -> (H, D)."""
     H, D = q.shape
@@ -67,14 +67,16 @@ def decode_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     assert S % BLOCK_S == 0
     qg = q.reshape(G, rep, D)
     grid = (G, S // BLOCK_S)
+    # the cache goes in group-major (G, S, D): a (1, BLOCK_S, D) block
+    # keeps the TPU-tiled last two dims whole, a (BLOCK_S, 1, D) one not
     out = pl.pallas_call(
         _kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),        # cache_len (1,)
             pl.BlockSpec((1, rep, D), lambda g, s: (g, 0, 0)),
-            pl.BlockSpec((BLOCK_S, 1, D), lambda g, s: (s, g, 0)),
-            pl.BlockSpec((BLOCK_S, 1, D), lambda g, s: (s, g, 0)),
+            pl.BlockSpec((1, BLOCK_S, D), lambda g, s: (g, s, 0)),
+            pl.BlockSpec((1, BLOCK_S, D), lambda g, s: (g, s, 0)),
         ],
         out_specs=pl.BlockSpec((1, rep, D), lambda g, s: (g, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((G, rep, D), q.dtype),
@@ -84,5 +86,6 @@ def decode_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
             pltpu.VMEM((rep, D), jnp.float32),
         ],
         interpret=interpret,
-    )(cache_len, qg, k, v)
+        name="decode_attention",
+    )(cache_len, qg, k.transpose(1, 0, 2), v.transpose(1, 0, 2))
     return out.reshape(H, D)

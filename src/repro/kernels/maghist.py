@@ -4,11 +4,12 @@ First pass of accelerator-native top-k: bucket |g| by binary exponent into
 NBINS counters; a tiny jnp epilogue (:func:`threshold_from_hist`) picks
 the threshold bin so that >= r entries survive, and only candidates are
 ranked exactly. All-d work (the expensive part) is one streaming pass,
-VMEM-tiled. Two kernels share the bin math: the single-vector
-:func:`maghist` (one program per d-block, per-block histograms) and the
-batched :func:`maghist_batch` ((N, d)-grid, one program per
-(row, d-block) tile, per-row histograms accumulated across blocks — the
-production candidate plane in ``ops.threshold_topk_batch``).
+VMEM-tiled. One kernel, :func:`maghist_batch` ((N, d)-grid, one
+program per (row-block, d-block) tile, per-row histograms accumulated
+across blocks), serves both the production candidate plane
+(``ops.threshold_topk_batch``) and the single-vector per-block
+histograms of ``ops.maghist`` (a (d,) vector viewed as
+(d // BLOCK_D, BLOCK_D) rows).
 
 Bins come from the EXACT float32 exponent field (bitcast, not
 ``floor(log2)``): ``bin = clip(exponent(|g|) + OFFSET, 0, NBINS-1)``.
@@ -41,63 +42,51 @@ def exponent_bins(mag: jnp.ndarray) -> jnp.ndarray:
     return jnp.where(mag != mag, 0, b)                   # NaN -> bin 0
 
 
-def _hist_block(g: jnp.ndarray) -> jnp.ndarray:
-    """(block,) raw values -> (NBINS,) int32 one-pass histogram."""
-    b = exponent_bins(jnp.abs(g.astype(jnp.float32)))
-    onehot = (b[:, None] == jax.lax.broadcasted_iota(
-        jnp.int32, (g.shape[0], NBINS), 1)).astype(jnp.int32)
-    return jnp.sum(onehot, axis=0)
+ROWS = 8             # row block of the batched kernel (TPU sublane tile)
 
 
 def _kernel(g_ref, hist_ref):
-    hist_ref[...] = _hist_block(g_ref[...])[None, :]
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def maghist(g: jnp.ndarray, *, interpret: bool = True) -> jnp.ndarray:
-    """g: (d,) with d % BLOCK_D == 0 -> (d // BLOCK_D, NBINS) int32."""
-    d = g.shape[0]
-    assert d % BLOCK_D == 0
-    nb = d // BLOCK_D
-    return pl.pallas_call(
-        _kernel,
-        grid=(nb,),
-        in_specs=[pl.BlockSpec((BLOCK_D,), lambda j: (j,))],
-        out_specs=pl.BlockSpec((1, NBINS), lambda j: (j, 0)),
-        out_shape=jax.ShapeDtypeStruct((nb, NBINS), jnp.int32),
-        interpret=interpret,
-    )(g)
-
-
-def _batch_kernel(g_ref, hist_ref):
-    j = pl.program_id(1)
-
-    @pl.when(j == 0)
+    """One (ROWS, block_d) tile: per-row bin counts, accumulated across
+    the inner d-block grid axis. One compare-and-count pass per bin keeps
+    every intermediate a lane-dense (ROWS, block_d) vector — no one-hot
+    with a bin axis, no lane->sublane reshape."""
+    @pl.when(pl.program_id(1) == 0)
     def _init():
         hist_ref[...] = jnp.zeros_like(hist_ref)
 
-    hist_ref[...] += _hist_block(g_ref[0])[None, :]
+    b = exponent_bins(jnp.abs(g_ref[...].astype(jnp.float32)))
+    lanes = jax.lax.broadcasted_iota(jnp.int32, hist_ref.shape, 1)
+
+    def count(i, h):
+        c = jnp.sum((b == i).astype(jnp.int32), axis=1, keepdims=True)
+        return jnp.where(lanes == i, c, h)
+
+    hist_ref[...] += jax.lax.fori_loop(0, NBINS, count,
+                                       jnp.zeros(hist_ref.shape, jnp.int32))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "block_d"))
-def maghist_batch(G: jnp.ndarray, *, interpret: bool = True,
+def maghist_batch(G: jnp.ndarray, *, interpret: bool = False,
                   block_d: int = BLOCK_D) -> jnp.ndarray:
-    """G: (N, d) with d % block_d == 0 -> (N, NBINS) int32 row histograms.
+    """G: (N, d) with N % ROWS == 0 and d % block_d == 0 -> (N, NBINS)
+    int32 row histograms (ops.py pads).
 
-    Grid (N, d // block_d): one program per (row, d-block) tile; the
-    per-row histogram accumulates across the inner (fastest-moving) block
-    dimension, exactly the revisiting pattern ``sparse_aggregate`` uses.
-    ``block_d`` is the autotune surface (kernels.autotune).
+    Grid (N // ROWS, d // block_d): one program per (row-block, d-block)
+    tile; the per-row histograms accumulate across the inner
+    (fastest-moving) block dimension, exactly the revisiting pattern
+    ``sparse_aggregate`` uses. ``block_d`` is the autotune surface
+    (kernels.autotune).
     """
     n, d = G.shape
-    assert d % block_d == 0
+    assert n % ROWS == 0 and d % block_d == 0
     return pl.pallas_call(
-        _batch_kernel,
-        grid=(n, d // block_d),
-        in_specs=[pl.BlockSpec((1, block_d), lambda i, j: (i, j))],
-        out_specs=pl.BlockSpec((1, NBINS), lambda i, j: (i, 0)),
+        _kernel,
+        grid=(n // ROWS, d // block_d),
+        in_specs=[pl.BlockSpec((ROWS, block_d), lambda i, j: (i, j))],
+        out_specs=pl.BlockSpec((ROWS, NBINS), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, NBINS), jnp.int32),
         interpret=interpret,
+        name="maghist_batch",
     )(G)
 
 

@@ -1,5 +1,6 @@
 """Jit'd public wrappers around the Pallas kernels: padding to block
-multiples, interpret-mode switch (CPU validation vs TPU target), the
+multiples, the platform rule for interpret mode (compiled on a TPU,
+interpreted only on the CPU, where tests and rehearsals run), the
 hybrid threshold-top-k built from the maghist kernel, and the autotune
 registry consultation (kernels.autotune) — every tiling argument left
 unspecified by the caller resolves through the persistent
@@ -19,21 +20,19 @@ from repro.kernels import segmented_topk as ST
 from repro.kernels import sparse_aggregate as SA
 from repro.kernels import decode_attention as DA
 
-# interpret=True executes the kernel bodies in Python on CPU; on a real TPU
-# runtime set repro_kernels_interpret(False).
-_INTERPRET = True
-
-
-def set_interpret(flag: bool):
-    global _INTERPRET
-    _INTERPRET = bool(flag)
+def _interpret() -> bool:
+    """Interpret mode is decided by the platform: the kernels run
+    compiled on a TPU and are emulated only on the CPU. A kernel the TPU
+    compiler refuses is a bug to fix, never a reason to emulate it on
+    the chip."""
+    return jax.default_backend() == "cpu"
 
 
 def backend_tag() -> str:
-    """Autotune backend key: the platform, plus '+interp' while the
+    """Autotune backend key: the platform, plus '+interp' where the
     kernels run in interpret mode (emulation timings must never be
     confused with real-TPU entries)."""
-    return jax.default_backend() + ("+interp" if _INTERPRET else "")
+    return jax.default_backend() + ("+interp" if _interpret() else "")
 
 
 def _tuned(kernel: str, shape, dtype, defaults: dict) -> dict:
@@ -74,7 +73,7 @@ def sparse_aggregate(idx: jnp.ndarray, vals: jnp.ndarray, age: jnp.ndarray,
     vals_p = _pad_to(vals.astype(jnp.float32), nk_tile, fill=0)
     age_p = _pad_to(age.astype(jnp.int32), block_d, fill=0)
     dense, new_age = SA.sparse_aggregate(idx_p, vals_p, age_p,
-                                         interpret=_INTERPRET,
+                                         interpret=_interpret(),
                                          block_d=block_d, nk_tile=nk_tile)
     return dense[:d], new_age[:d]
 
@@ -103,26 +102,28 @@ def segmented_age_topk(cand: jnp.ndarray, cand_age: jnp.ndarray,
                            constant_values=ST.NEG)
     return ST.segmented_age_topk(cand, cand_age,
                                  valid.astype(jnp.int32), k,
-                                 disjoint=disjoint, interpret=_INTERPRET)
+                                 disjoint=disjoint, interpret=_interpret())
 
 
 def maghist(g: jnp.ndarray):
+    """Per-block histograms of one vector: (d,) -> (ceil(d / BLOCK_D),
+    NBINS) int32 — the batched kernel's rows over the (nb, BLOCK_D)
+    view of the zero-padded vector."""
     gp = _pad_to(g, MH.BLOCK_D, fill=0)
-    return MH.maghist(gp, interpret=_INTERPRET)
+    return maghist_batch(gp.reshape(-1, MH.BLOCK_D), block_d=MH.BLOCK_D)
 
 
 def maghist_batch(G: jnp.ndarray, *, block_d: int | None = None):
     """Batched magnitude histograms via the (N, d)-grid Pallas kernel:
     (N, d) -> (N, NBINS) int32. Pads d with zeros (bottom bin — they can
     only inflate the bin-0 count, which the tau = 0 epilogue rule makes
-    harmless). block_d resolves through the autotune registry."""
+    harmless; zero pad rows are sliced back off). block_d resolves
+    through the autotune registry."""
     n, d = G.shape
     block_d = block_d or _tuned("maghist_batch", (n, d), G.dtype,
                                 {"block_d": MH.BLOCK_D})["block_d"]
-    pad = (-d) % block_d
-    if pad:
-        G = jnp.pad(G, ((0, 0), (0, pad)))
-    return MH.maghist_batch(G, interpret=_INTERPRET, block_d=block_d)
+    G = jnp.pad(G, ((0, (-n) % MH.ROWS), (0, (-d) % block_d)))
+    return MH.maghist_batch(G, interpret=_interpret(), block_d=block_d)[:n]
 
 
 def _masked_topr(mag: jnp.ndarray, tau: jnp.ndarray, r: int):
@@ -152,11 +153,11 @@ def threshold_topk_batch(G: jnp.ndarray, r: int, *,
     pass; hist_impl picks it ('pallas' = the (N, d)-grid
     ``maghist_batch`` kernel + the vectorized histogram epilogue,
     'jnp' = the scatter-free binary-search tau, identical bit-for-bit;
-    None routes pallas on a real backend and jnp under interpret mode,
-    where emulating the kernel would be Python-speed).
+    None routes pallas on a TPU and jnp on the CPU, where emulating the
+    kernel would be Python-speed).
     """
     if hist_impl is None:
-        hist_impl = "jnp" if _INTERPRET else "pallas"
+        hist_impl = "jnp" if _interpret() else "pallas"
     mag = jnp.abs(G.astype(jnp.float32))
     tau = (MH.threshold_from_hist_batch(maghist_batch(G), r)
            if hist_impl == "pallas" else MH.threshold_search(mag, r))
@@ -175,8 +176,8 @@ def threshold_topk(g: jnp.ndarray, r: int):
     is exactly ``lax.top_k(where(isnan, -1, |g|), r)``.
     """
     mag = jnp.abs(g.astype(jnp.float32))[None, :]
-    tau = (MH.threshold_from_hist(maghist(g), r)[None] if not _INTERPRET
-           else MH.threshold_search(mag, r))
+    tau = (MH.threshold_search(mag, r) if _interpret()
+           else MH.threshold_from_hist(maghist(g), r)[None])
     vals, idx = _masked_topr(mag, tau, r)
     return vals[0], idx[0]
 
@@ -192,5 +193,5 @@ def decode_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         k = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
     clen = jnp.full((1,), cache_len, jnp.int32)
-    fn = functools.partial(DA.decode_attention, interpret=_INTERPRET)
+    fn = functools.partial(DA.decode_attention, interpret=_interpret())
     return jax.vmap(lambda qq, kk, vv: fn(qq, kk, vv, clen))(q, k, v)

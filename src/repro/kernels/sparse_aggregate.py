@@ -5,15 +5,19 @@ sparse client updates into the dense gradient AND apply eq. (2) to the age
 vector. Random-index scatter is slow on TPU vector units, so each VMEM
 block turns the scatter into a ONE-HOT MATMUL on the MXU:
 
-    out_block[B] = vals[NK] @ onehot(idx_local)[NK, B]
+    [sum; hits][2, B] = [vals; ones][2, NK] @ onehot(idx_local)[NK, B]
 
 which is exactly how TPUs like to scatter (dense systolic work, no
-data-dependent addressing). The age update reuses the same one-hot:
-hit = any(onehot) -> age' = (age + 1) * (1 - hit).
+data-dependent addressing). The second LHS row counts the hits in the
+same matmul, and the age update reads them:
+age' = 0 where hit, age + 1 elsewhere.
 
-Block size 512 lanes (f32) keeps the (NK, B) one-hot in VMEM for NK up to
-~16k (16k x 512 x 4B = 32 MB is too big — so NK is tiled too, at NK_TILE
-2048 -> 4 MB one-hot tiles, accumulated over a second grid dim).
+Layouts follow the TPU's (8, 128) tiling: the indices arrive as an
+(NK, 1) column (so the one-hot is a sublane-by-lane compare, no
+in-kernel transpose), the [vals; ones] LHS and every d-sized operand as
+lane-dense (rows, d) arrays. NK is tiled by ``nk_tile`` (a second,
+accumulating grid dim), which bounds the (nk_tile, block_d) f32 one-hot
+in VMEM: 2048 x 512 x 4 B = 4 MiB at the defaults.
 """
 from __future__ import annotations
 
@@ -22,22 +26,20 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 BLOCK_D = 512
 NK_TILE = 2048
 
 
-def _kernel(idx_ref, vals_ref, age_ref, out_ref, age_out_ref, hit_ref, *,
+def _kernel(idx_ref, lhs_ref, age_ref, out_ref, age_out_ref, hit_ref, *,
             block_d: int, nk_tile: int):
     j = pl.program_id(0)        # d-block index
     t = pl.program_id(1)        # NK tile index
     nt = pl.num_programs(1)
 
-    idx = idx_ref[...]                            # (nk_tile,) int32
-    vals = vals_ref[...].astype(jnp.float32)      # (nk_tile,)
-    lo = j * block_d
-    local = idx - lo
-    onehot = (local[:, None] == jax.lax.broadcasted_iota(
+    local = idx_ref[...] - j * block_d                      # (nk_tile, 1)
+    onehot = (local == jax.lax.broadcasted_iota(
         jnp.int32, (nk_tile, block_d), 1)).astype(jnp.float32)
 
     @pl.when(t == 0)
@@ -45,20 +47,22 @@ def _kernel(idx_ref, vals_ref, age_ref, out_ref, age_out_ref, hit_ref, *,
         out_ref[...] = jnp.zeros_like(out_ref)
         hit_ref[...] = jnp.zeros_like(hit_ref)
 
-    out_ref[...] += jnp.dot(vals[None, :], onehot,
-                            preferred_element_type=jnp.float32)[0]
-    hit_ref[...] += jnp.sum(onehot, axis=0)
+    # HIGHEST: the one-hot is exact in any precision, the values are not —
+    # a single bf16 pass would round every aggregated f32 payload
+    acc = jnp.dot(lhs_ref[...], onehot, preferred_element_type=jnp.float32,
+                  precision=jax.lax.Precision.HIGHEST)      # (2, block_d)
+    out_ref[...] += acc[0:1]
+    hit_ref[...] += acc[1:2]
 
     @pl.when(t == nt - 1)
     def _fini():
-        hit = hit_ref[...] > 0
-        age_out_ref[...] = jnp.where(hit, 0, age_ref[...] + 1)
+        age_out_ref[...] = jnp.where(hit_ref[...] > 0, 0, age_ref[...] + 1)
 
 
 @functools.partial(jax.jit,
                    static_argnames=("interpret", "block_d", "nk_tile"))
 def sparse_aggregate(idx: jnp.ndarray, vals: jnp.ndarray, age: jnp.ndarray,
-                     *, interpret: bool = True, block_d: int = BLOCK_D,
+                     *, interpret: bool = False, block_d: int = BLOCK_D,
                      nk_tile: int = NK_TILE):
     """idx/vals: (NK,) flattened client payloads (int32 / float); duplicate
     indices accumulate. age: (d,) int32. Returns (dense (d,) f32, new_age).
@@ -71,24 +75,23 @@ def sparse_aggregate(idx: jnp.ndarray, vals: jnp.ndarray, age: jnp.ndarray,
     nk = idx.shape[0]
     assert d % block_d == 0 and nk % nk_tile == 0
     grid = (d // block_d, nk // nk_tile)
-    out, new_age, _ = pl.pallas_call(
+    lhs = jnp.stack([vals.astype(jnp.float32), jnp.ones((nk,), jnp.float32)])
+    row = pl.BlockSpec((1, block_d), lambda j, t: (0, j))
+    out, new_age = pl.pallas_call(
         functools.partial(_kernel, block_d=block_d, nk_tile=nk_tile),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((nk_tile,), lambda j, t: (t,)),
-            pl.BlockSpec((nk_tile,), lambda j, t: (t,)),
-            pl.BlockSpec((block_d,), lambda j, t: (j,)),
+            pl.BlockSpec((nk_tile, 1), lambda j, t: (t, 0)),
+            pl.BlockSpec((2, nk_tile), lambda j, t: (0, t)),
+            row,
         ],
-        out_specs=[
-            pl.BlockSpec((block_d,), lambda j, t: (j,)),
-            pl.BlockSpec((block_d,), lambda j, t: (j,)),
-            pl.BlockSpec((block_d,), lambda j, t: (j,)),
-        ],
+        out_specs=[row, row],
         out_shape=[
-            jax.ShapeDtypeStruct((d,), jnp.float32),
-            jax.ShapeDtypeStruct((d,), jnp.int32),
-            jax.ShapeDtypeStruct((d,), jnp.float32),   # hit scratch-as-output
+            jax.ShapeDtypeStruct((1, d), jnp.float32),
+            jax.ShapeDtypeStruct((1, d), jnp.int32),
         ],
+        scratch_shapes=[pltpu.VMEM((1, block_d), jnp.float32)],  # hits
         interpret=interpret,
-    )(idx, vals, age)
-    return out, new_age
+        name="sparse_aggregate",
+    )(idx.reshape(nk, 1), lhs, age.reshape(1, d))
+    return out[0], new_age[0]

@@ -16,7 +16,6 @@ benchmarks/roofline.py renders EXPERIMENTS.md tables from these.
 '''
 import argparse
 import json
-import re
 import sys
 import time
 import traceback
@@ -25,8 +24,8 @@ import jax
 import numpy as np
 
 from repro.configs import ASSIGNED_ARCHS, INPUT_SHAPES, get_config
-from repro.launch.mesh import (HBM_BW, ICI_BW, PEAK_FLOPS_BF16,
-                               make_production_mesh)
+from repro.launch.hlo_cost import collective_bytes, cost_dict
+from repro.launch.mesh import make_production_mesh, peaks
 from repro.launch.steps import lower_combo
 
 # combinations that do not exist architecturally (DESIGN.md §4)
@@ -35,65 +34,15 @@ SKIPS = {
                                        " 500k-frame context does not exist",
 }
 
-_DTYPE_BYTES = {
-    "f64": 8, "f32": 4, "f16": 2, "bf16": 2, "f8": 1,
-    "s64": 8, "u64": 8, "s32": 4, "u32": 4, "s16": 2, "u16": 2,
-    "s8": 1, "u8": 1, "pred": 1, "c64": 8, "c128": 16,
-}
-
-_COLL_RE = re.compile(
-    r"(\w[\w.\-]*)\s*=\s*(\(?[^=]*?\)?)\s*"
-    r"(all-reduce|all-gather|reduce-scatter|all-to-all|collective-permute)"
-    r"(?:-start)?\(", )
-
-
-def _shape_bytes(shape_str: str) -> int:
-    """'f32[16,32]' or tuple '(f32[4], bf16[2,3])' -> total bytes."""
-    total = 0
-    for m in re.finditer(r"(\w+)\[([\d,]*)\]", shape_str):
-        dt, dims = m.group(1), m.group(2)
-        if dt not in _DTYPE_BYTES:
-            continue
-        n = 1
-        if dims:
-            for d in dims.split(","):
-                n *= int(d)
-        total += n * _DTYPE_BYTES[dt]
-    return total
-
-
-def collective_bytes(hlo_text: str) -> dict:
-    """Sum output-shape bytes of every collective op in the HLO (per-device
-    program => per-device bytes), by op kind."""
-    out = {"all-reduce": 0, "all-gather": 0, "reduce-scatter": 0,
-           "all-to-all": 0, "collective-permute": 0}
-    for line in hlo_text.splitlines():
-        s = line.strip()
-        m = re.match(r"^[%\w][\w.\-]*\s*=\s*(.*?)\s*"
-                     r"(all-reduce|all-gather|reduce-scatter|all-to-all|"
-                     r"collective-permute)(-start)?\(", s)
-        if not m:
-            continue
-        out[m.group(2)] += _shape_bytes(m.group(1))
-    return out
-
 
 def roofline_terms(flops_per_dev: float, bytes_per_dev: float,
                    coll_bytes_per_dev: float) -> dict:
+    pk = peaks("TPU v5 lite")          # the production meshes are v5e
     return {
-        "compute_s": flops_per_dev / PEAK_FLOPS_BF16,
-        "memory_s": bytes_per_dev / HBM_BW,
-        "collective_s": coll_bytes_per_dev / ICI_BW,
+        "compute_s": flops_per_dev / pk["flops_bf16"],
+        "memory_s": bytes_per_dev / pk["hbm_bw"],
+        "collective_s": coll_bytes_per_dev / pk["ici_bw"],
     }
-
-
-def cost_dict(compiled) -> dict:
-    """compiled.cost_analysis() returns [dict] on jax 0.4.x, dict on
-    newer versions — normalize to a dict."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca
 
 
 def _metrics(compiled) -> dict:

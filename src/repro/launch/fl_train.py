@@ -17,6 +17,7 @@ from repro.configs.base import RAgeKConfig
 from repro.data.federated import paper_cifar_split, paper_mnist_split
 from repro.data.synthetic import cifar10_like, mnist_like
 from repro.fl import AsyncService, FaultModel, FederatedEngine, LatencyModel
+from repro.launch.compile_cache import use_compile_cache
 
 
 class _KillingCheckpointer(AsyncCheckpointer):
@@ -38,7 +39,7 @@ class _KillingCheckpointer(AsyncCheckpointer):
             os._exit(17)
 
 
-def main():
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--dataset", choices=("mnist", "cifar"), default="mnist")
     ap.add_argument("--method", default="rage_k",
@@ -173,8 +174,13 @@ def main():
                          "the first checkpoint at/past this round "
                          "commits (requires --ckpt-dir and "
                          "--ckpt-every)")
-    args = ap.parse_args()
+    return ap
 
+
+def build_federation(args):
+    """The federation the arguments describe: (kind, shards, test, hp) —
+    the paper's Table-I network, its non-i.i.d. split of the seeded
+    synthetic dataset, and the protocol config."""
     if args.dataset == "mnist":
         defaults = (dict(r=75, k=10, H=4, M=20, lr=1e-4, batch_size=256)
                     if args.paper_hparams
@@ -209,10 +215,27 @@ def main():
                      staleness_eta=args.staleness_eta,
                      version_window=args.version_window,
                      age_layout=args.age_layout, **defaults)
+    return kind, shards, test, hp
 
-    faults = (FaultModel.parse(args.faults, len(shards), seed=args.seed)
-              if args.faults else None)
-    quarantine = not args.no_quarantine
+
+def _faults(args, n: int):
+    return (FaultModel.parse(args.faults, n, seed=args.seed)
+            if args.faults else None)
+
+
+def build_engine(args) -> FederatedEngine:
+    """The sync drivers' engine over :func:`build_federation`."""
+    kind, shards, test, hp = build_federation(args)
+    return FederatedEngine(kind, shards, test, hp, seed=args.seed,
+                           ef=args.ef, aggregate_impl=args.aggregate,
+                           selection=args.selection, compute=args.compute,
+                           faults=_faults(args, len(shards)),
+                           quarantine=not args.no_quarantine)
+
+
+def main():
+    args = build_parser().parse_args()
+    use_compile_cache()
     ck = None
     if args.ckpt_dir:
         ck = (_KillingCheckpointer(args.ckpt_dir, args.kill_at_round)
@@ -221,11 +244,13 @@ def main():
         raise SystemExit("--kill-at-round needs --ckpt-dir/--ckpt-every")
 
     if args.driver == "async":
+        kind, shards, test, hp = build_federation(args)
         latency = LatencyModel(len(shards), hetero=args.hetero,
                                jitter=args.jitter, seed=args.seed)
         svc = AsyncService(kind, shards, test, hp, seed=args.seed,
                            latency=latency, solicit=args.solicit,
-                           faults=faults, quarantine=quarantine)
+                           faults=_faults(args, len(shards)),
+                           quarantine=not args.no_quarantine)
         if args.resume and ck is not None and ck.latest_step() is not None:
             svc.load_state(ck)
             print(f"resumed from aggregation {svc.aggs_done} "
@@ -262,10 +287,7 @@ def main():
                           f, indent=1)
         return
 
-    engine = FederatedEngine(kind, shards, test, hp, seed=args.seed,
-                             ef=args.ef, aggregate_impl=args.aggregate,
-                             selection=args.selection, compute=args.compute,
-                             faults=faults, quarantine=quarantine)
+    engine = build_engine(args)
     prior = None
     if args.resume and ck is not None and ck.latest_step() is not None:
         prior = engine.load_state(ck)

@@ -12,6 +12,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import get_config, get_smoke_config, list_archs
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import transformer as T
 
 
@@ -24,6 +25,7 @@ def main():
     ap.add_argument("--gen", type=int, default=16)
     ap.add_argument("--temperature", type=float, default=0.0)
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     cfg = cfg.replace(remat=False)

@@ -20,6 +20,7 @@ from repro.configs import get_config, get_smoke_config, list_archs
 from repro.configs.base import InputShape
 from repro.data.pipeline import token_stream
 from repro.dist.sparse_sync import init_age_state, make_sync_train_step
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models import transformer as T
 from repro.models.registry import input_specs
@@ -42,6 +43,7 @@ def main():
     ap.add_argument("--log-every", type=int, default=10)
     ap.add_argument("--ckpt", default=None)
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     cfg = cfg.replace(remat=False)

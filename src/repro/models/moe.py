@@ -24,10 +24,12 @@ from repro.dist.sharding import active_mesh, constraint
 from repro.models.layers import act_fn, dense_init, mlp_params, apply_mlp
 
 
-# f32 MXU accumulation on TPU; the CPU runtime's DotThunk can't execute
-# batched BF16xBF16=F32 dots (tests run the kernel math in bf16 there —
-# the dry-run only compiles, so the TPU artifact keeps f32 accumulation)
-_ACC = jnp.float32 if jax.default_backend() != "cpu" else None
+def _acc_dtype():
+    """f32 MXU accumulation on TPU; the CPU runtime's DotThunk can't
+    execute batched BF16xBF16=F32 dots, so the CPU runs the expert math
+    in bf16. Asked when the layer is traced, not when the module is
+    imported, so importing never initializes a backend."""
+    return jnp.float32 if jax.default_backend() != "cpu" else None
 
 
 def moe_params(key, cfg) -> dict:
@@ -122,13 +124,14 @@ def apply_moe(p: dict, cfg, x: jnp.ndarray):
     buf = constraint(buf, ("batch", "expert", None, None))
 
     a = act_fn(cfg.act)
+    acc = _acc_dtype()
     h = a(jnp.einsum("becd,edf->becf", buf, p["experts_w1"],
-                     preferred_element_type=_ACC).astype(x.dtype))
+                     preferred_element_type=acc).astype(x.dtype))
     h = h * jnp.einsum("becd,edf->becf", buf, p["experts_w3"],
-                       preferred_element_type=_ACC).astype(x.dtype)
+                       preferred_element_type=acc).astype(x.dtype)
     h = constraint(h, ("batch", "expert", None, "d_ff"))
     out_buf = jnp.einsum("becf,efd->becd", h, p["experts_w2"],
-                         preferred_element_type=_ACC).astype(x.dtype)
+                         preferred_element_type=acc).astype(x.dtype)
     out_buf = constraint(out_buf, ("batch", "expert", None, None))
 
     # block-local combine

@@ -33,7 +33,7 @@ from repro.data.pipeline import DeviceShardStore
 from repro.data.synthetic import cifar10_like, mnist_like
 from repro.fl import FederatedEngine
 from repro.fl import client as C
-from repro.launch.dryrun import cost_dict
+from repro.launch.hlo_cost import cost_dict
 from repro.models import paper_nets as P
 
 METHODS = ("rage_k", "rtop_k", "top_k", "random_k", "dense")
@@ -97,13 +97,22 @@ def _assert_same_engine(ea, eb):
                                       np.asarray(eb.ef_mem))
 
 
-def _step_parity(em, eg, rounds):
+def _step_parity(em, eg, rounds, loss_ulp=0):
     """Drive both engines round-at-a-time, comparing every per-round
     metric (assert_array_equal treats the NaN loss rows of inactive
-    clients as equal)."""
+    clients as equal). ``loss_ulp`` > 0 bounds the participants' losses
+    at that many ulp instead of bitwise (the cnn contract, DESIGN.md
+    §11); everything else stays bitwise."""
     for _ in range(rounds):
         mm, mg = em.step(), eg.step()
-        np.testing.assert_array_equal(mm["losses"], mg["losses"])
+        if loss_ulp:
+            held = np.isnan(mm["losses"])
+            np.testing.assert_array_equal(held, np.isnan(mg["losses"]))
+            np.testing.assert_array_max_ulp(mm["losses"][~held],
+                                            mg["losses"][~held],
+                                            maxulp=loss_ulp)
+        else:
+            np.testing.assert_array_equal(mm["losses"], mg["losses"])
         assert np.isnan(mm["losses"]).sum() == em.n - mm["n_active"]
         if mm["idx"] is None:
             assert mg["idx"] is None
@@ -182,7 +191,10 @@ def test_gathered_equals_masked_ef(mnist_setup, method):
 def test_gathered_equals_masked_cnn(cifar_setup):
     """cnn kind: BatchNorm running stats are per-client state rows —
     gathered trains m of them and scatters back; held clients' stats
-    must come out untouched."""
+    must come out untouched. XLA's CPU GEMM picks its blocking from the
+    row count, so the dense layers' K-sums of an (m*B)-row program and an
+    (N*B)-row one may round differently: losses are bounded at 4 ulp
+    (1 measured), state and indices stay bitwise (DESIGN.md §11)."""
     shards, test = cifar_setup
     hp = RAgeKConfig(r=200, k=20, H=1, M=2, lr=1e-3, batch_size=8,
                      method="rage_k", schedule="uniform",
@@ -192,7 +204,7 @@ def test_gathered_equals_masked_cnn(cifar_setup):
     eg = FederatedEngine("cnn", shards, test, hp, seed=1,
                          compute="gathered")
     assert eg.state_s                       # BatchNorm state present
-    _step_parity(em, eg, 5)
+    _step_parity(em, eg, 5, loss_ulp=4)
 
 
 # ---------------------------------------------------------------------------

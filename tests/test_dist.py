@@ -4,6 +4,7 @@ semantics)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 from jax.sharding import PartitionSpec as P
 
 from repro.dist import sharding as SH
@@ -19,6 +20,11 @@ def test_resolve_spec_divisibility_fallback():
         # resolutions collapse to replication
         spec = SH.resolve_spec(("heads", "d_ff"), (10, 7))
         assert spec == P(None, None)
+
+
+def test_host_mesh_refuses_more_devices_than_exist():
+    with pytest.raises(ValueError, match="devices"):
+        make_host_mesh(len(jax.devices()) + 1, 1)
 
 
 def test_param_specs_structure_matches():
@@ -213,7 +219,6 @@ def test_buffered_sync_flush_cadence_mean_and_aging():
 
 
 def test_buffered_sync_validates_k():
-    import pytest
     from repro.dist.sparse_sync import make_buffered_sync
     mesh = make_host_mesh(1, 1)
     g = {"a": jnp.zeros((4,))}

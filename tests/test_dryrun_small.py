@@ -7,7 +7,7 @@ import pytest
 
 from repro.configs import get_smoke_config
 from repro.configs.base import InputShape
-from repro.launch.dryrun import cost_dict
+from repro.launch.hlo_cost import cost_dict
 from repro.launch.mesh import make_host_mesh
 from repro.launch.steps import lower_combo
 
@@ -29,7 +29,7 @@ def test_lower_compile_small(arch, shape):
 
 
 def test_collective_parser():
-    from repro.launch.dryrun import collective_bytes, _shape_bytes
+    from repro.launch.hlo_cost import collective_bytes, _shape_bytes
     hlo = """
   %ag = f32[16,32]{1,0} all-gather(%x), dimensions={0}
   %ar.1 = (bf16[8,8]{1,0}, bf16[8,8]{1,0}) all-reduce-start(%a, %b)

@@ -9,7 +9,7 @@ import pytest
 from repro.configs import get_smoke_config
 from repro.configs.base import InputShape
 from repro.launch.mesh import make_host_mesh
-from repro.launch.dryrun import cost_dict
+from repro.launch.hlo_cost import cost_dict
 from repro.launch.steps import lower_combo
 
 TRAIN = InputShape("t", 64, 2, "train")
