@@ -47,27 +47,17 @@ def interleaved_best_us(fns: dict, *, iters: int, rounds: int) -> dict:
     return best
 
 
-def interleaved_best(fns: dict, *, repeats: int, before=None, after=None):
+def interleaved_best(fns: dict, *, repeats: int) -> dict:
     """Best-of wall-clock (seconds), one call per variant per repeat,
-    variants interleaved. ``before(name)`` runs untimed ahead of each
-    call (state reset); ``after(name, wall_s)`` may return a dict of
-    side metrics kept only for the best repeat. Returns (best, extras).
-    Callers warm their callables first — the first repeat still pays any
-    residual compilation."""
+    variants interleaved. Callers warm their callables first — the first
+    repeat still pays any residual compilation."""
     best = {name: float("inf") for name in fns}
-    extras = {name: {} for name in fns}
     for _ in range(repeats):
         for name, fn in fns.items():
-            if before is not None:
-                before(name)
             t0 = time.perf_counter()
             fn()
-            wall = time.perf_counter() - t0
-            if wall < best[name]:
-                best[name] = wall
-                if after is not None:
-                    extras[name] = after(name, wall) or {}
-    return best, extras
+            best[name] = min(best[name], time.perf_counter() - t0)
+    return best
 
 
 def save_json(name: str, obj):

@@ -1,9 +1,8 @@
 """Engine driver bench on the fig3 MNIST config, three axes:
 
 * DRIVER: step (one dispatch per round) vs scan (chunked lax.scan) —
-  records rounds/sec and the host-dispatch fraction (share of wall time
-  the driver spends OUTSIDE blocking device calls: python loop, metrics
-  pulls, reclustering);
+  records rounds/sec (where the driver's host time goes is read from a
+  profiler trace: the engine's ``fl.*`` spans);
 * SELECTION plane (rage_k): segmented per-cluster parallel (default) vs
   the sequential all-clients scan — both under the scan driver;
 * ASYNC RECLUSTER: a short run whose final round triggers the every-M
@@ -236,7 +235,7 @@ def _active_compute(rounds: int, repeats: int) -> dict:
                      "compute": engine._compute,
                      "round_flops": flops(engine)}
         engine.run_scanned(rounds, eval_every=rounds)   # compile + warm
-    best, _ = interleaved_best(
+    best = interleaved_best(
         {name: (lambda e_=engine: e_.run_scanned(rounds,
                                                  eval_every=rounds))
          for name, engine in variants.items()},
@@ -330,7 +329,7 @@ def _age_memory(rounds: int, repeats: int) -> dict:
     for e in engines.values():
         for _ in range(2):
             e.step()                               # compile + warm
-    best, _ = interleaved_best(
+    best = interleaved_best(
         {lay: (lambda e_=e: [e_.step() for _ in range(rounds)])
          for lay, e in engines.items()},
         repeats=repeats)
@@ -393,7 +392,7 @@ def _resilience(shards, test, rounds: int, repeats: int,
         eng.run_scanned(ck_rounds, eval_every=ck_rounds,
                         checkpointer=ck, ckpt_every=every)
         engines[name] = (eng, ck, every)
-    best, _ = interleaved_best(
+    best = interleaved_best(
         {name: (lambda e_=eng, c_=ck, ev_=every:
                 e_.run_scanned(ck_rounds, eval_every=ck_rounds,
                                checkpointer=c_, ckpt_every=ev_))
@@ -458,13 +457,10 @@ def main(fast: bool = True):
         run = engine.run if driver == "step" else engine.run_scanned
         run(rounds, eval_every=rounds)                # compile + warm
         runs[name] = (engine, run)
-    best, extras = interleaved_best(
+    best = interleaved_best(
         {name: (lambda r_=run: r_(rounds, eval_every=rounds))
          for name, (engine, run) in runs.items()},
-        repeats=repeats,
-        before=lambda name: setattr(runs[name][0], "device_s", 0.0),
-        after=lambda name, wall: {
-            "host_frac": max(0.0, 1.0 - runs[name][0].device_s / wall)})
+        repeats=repeats)
 
     out = {"config": {"rounds": rounds, "repeats": repeats,
                       "method": hp.method, "r": hp.r, "k": hp.k,
@@ -472,12 +468,10 @@ def main(fast: bool = True):
     rows = []
     for name, driver, sel in VARIANTS:
         m = {"rounds_per_s": rounds / best[name],
-             "host_dispatch_fraction": extras[name].get("host_frac", 0.0),
              "wall_s": best[name], "driver": driver, "selection": sel}
         out[name] = m
         rows.append((f"engine_{name}", 1e6 / m["rounds_per_s"],
-                     f"rounds_per_s={m['rounds_per_s']:.2f};"
-                     f"host_dispatch_frac={m['host_dispatch_fraction']:.3f}"))
+                     f"rounds_per_s={m['rounds_per_s']:.2f}"))
     speedup = out["scan"]["rounds_per_s"] / out["step"]["rounds_per_s"]
     out["scan_speedup"] = speedup
     out["selection_speedup"] = (out["scan"]["rounds_per_s"]

@@ -279,7 +279,7 @@ def _selection_bench(fast: bool, rows: list) -> None:
         e._num_seg, e._max_seg = c, s
         e.run(rounds, eval_every=rounds)            # compile + warm
         engines[sel] = e
-    best_eng, _ = interleaved_best(
+    best_eng = interleaved_best(
         {sel: (lambda e_=e: e_.run(rounds, eval_every=rounds))
          for sel, e in engines.items()},
         repeats=repeats)

@@ -116,8 +116,10 @@ def make_local_phase(apply_loss: Callable, lr: float, *,
             params_s, opt_s, state_s, batches)
         if ef is not None:
             G = G + ef
-        report = (client_candidates(G, report_r, report_impl)
-                  if report_r is not None else None)
+        report = None
+        if report_r is not None:
+            with jax.named_scope("candidate_report"):
+                report = client_candidates(G, report_r, report_impl)
         return params_s, opt_s, state_s, G, report, losses
 
     return jax.jit(phase)

@@ -73,6 +73,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.checkpoint.io import load_checkpoint
 from repro.configs.base import RAgeKConfig
@@ -734,8 +735,12 @@ class FederatedEngine:
         self._round = jax.jit(self._round_impl,
                               static_argnames=("num_segments", "max_seg"))
         self._chunks: dict = {}          # scan length -> jitted chunk
+        # (length, num_segments, max_seg) keys the chunk programs were
+        # built for; a new one compiles, and ``retraces`` counts it by
+        # cause: a new scan "length", or new "packing" bounds
+        self._chunk_keys: set = set()
+        self.retraces = {"length": 0, "packing": 0}
         self._eval = jax.jit(self._eval_impl)
-        self.device_s = 0.0              # wall spent blocking on device
 
         # --- async recluster (scan driver overlaps the every-M DBSCAN) ----
         self._recluster_pool: ThreadPoolExecutor | None = None
@@ -799,6 +804,12 @@ class FederatedEngine:
         and discards inactive rows. Either way the top-r candidate
         report is FUSED into the local phase (rage_k), so selection
         below never re-reads an (N, d) gradient matrix.
+
+        Each phase runs under a ``jax.named_scope`` — ``local_phase``
+        (with ``candidate_report`` nested in it by the client phase),
+        ``selection``, ``aggregation``, ``global_update`` — so a
+        profiler trace puts every device op down to its layer. Scopes
+        are HLO metadata only; the program is unchanged.
         """
         (g_params, g_opt_state, params_s, opt_s, state_s, age, ef_mem,
          key, samp, sched) = carry
@@ -823,54 +834,55 @@ class FederatedEngine:
             f_nan = f_inf = f_byz = f_drop = None
             n_crashed = jnp.int32(0)
         gathered = self._compute == "gathered"
-        if gathered:
-            # compact the active ids, ascending (nonzero preserves the
-            # client order every sequential contract — selection
-            # tie-breaks, scatter-add ordering — is stated in); padded
-            # slots carry the sentinel n: they read a clipped duplicate
-            # row, train dead weight, and write nothing back
-            mb = self._scheduler.m_bound
-            act_idx = jnp.nonzero(act, size=mb,
-                                  fill_value=n)[0].astype(jnp.int32)
-            slot_ok = act_idx < n
-            iclip = jnp.minimum(act_idx, jnp.int32(n - 1))
+        with jax.named_scope("local_phase"):
+            if gathered:
+                # compact the active ids, ascending (nonzero preserves the
+                # client order every sequential contract — selection
+                # tie-breaks, scatter-add ordering — is stated in); padded
+                # slots carry the sentinel n: they read a clipped duplicate
+                # row, train dead weight, and write nothing back
+                mb = self._scheduler.m_bound
+                act_idx = jnp.nonzero(act, size=mb,
+                                      fill_value=n)[0].astype(jnp.int32)
+                slot_ok = act_idx < n
+                iclip = jnp.minimum(act_idx, jnp.int32(n - 1))
 
-            def gather_rows(t):
-                return jax.tree_util.tree_map(lambda a: a[iclip], t)
+                def gather_rows(t):
+                    return jax.tree_util.tree_map(lambda a: a[iclip], t)
 
-            def put_rows(old, new):
-                return jax.tree_util.tree_map(
-                    lambda a, b: a.at[act_idx].set(b, mode="drop"),
-                    old, new)
+                def put_rows(old, new):
+                    return jax.tree_util.tree_map(
+                        lambda a, b: a.at[act_idx].set(b, mode="drop"),
+                        old, new)
 
-            bx, by, samp = self._store.draw_gathered(data, samp, hp.H,
-                                                     act_idx)
-            _, opt_c, state_c, g, cands_c, losses_c = self._local_phase(
-                gather_rows(params_s), gather_rows(opt_s),
-                gather_rows(state_s) if state_s else {}, (bx, by),
-                gather_rows(ef_mem) if ef_mem is not None else None)
-            opt_s = put_rows(opt_s, opt_c)
-            if state_s:
-                state_s = put_rows(state_s, state_c)
-            # inactive clients never trained: their loss is undefined —
-            # NaN, the same contract the masked path reports
-            losses = jnp.full((n,), jnp.nan, jnp.float32).at[
-                act_idx].set(losses_c, mode="drop")
-            cands = (jnp.zeros((n, hp.r), jnp.int32).at[act_idx].set(
-                cands_c, mode="drop") if cands_c is not None else None)
-        else:
-            act_idx = slot_ok = iclip = None
-            bx, by, samp2 = self._store.draw(data, samp, hp.H)
-            _, opt_s2, state_s2, g, cands, losses = self._local_phase(
-                params_s, opt_s, state_s if state_s else {}, (bx, by),
-                ef_mem)
-            # non-participants sit the round out: their local state holds
-            # and their data stream is not consumed
-            opt_s = _where_clients(act, opt_s2, opt_s)
-            samp = _where_clients(act, samp2, samp)
-            if state_s:
-                state_s = _where_clients(act, state_s2, state_s)
-            losses = jnp.where(act, losses, jnp.nan)
+                bx, by, samp = self._store.draw_gathered(data, samp, hp.H,
+                                                         act_idx)
+                _, opt_c, state_c, g, cands_c, losses_c = self._local_phase(
+                    gather_rows(params_s), gather_rows(opt_s),
+                    gather_rows(state_s) if state_s else {}, (bx, by),
+                    gather_rows(ef_mem) if ef_mem is not None else None)
+                opt_s = put_rows(opt_s, opt_c)
+                if state_s:
+                    state_s = put_rows(state_s, state_c)
+                # inactive clients never trained: their loss is undefined —
+                # NaN, the same contract the masked path reports
+                losses = jnp.full((n,), jnp.nan, jnp.float32).at[
+                    act_idx].set(losses_c, mode="drop")
+                cands = (jnp.zeros((n, hp.r), jnp.int32).at[act_idx].set(
+                    cands_c, mode="drop") if cands_c is not None else None)
+            else:
+                act_idx = slot_ok = iclip = None
+                bx, by, samp2 = self._store.draw(data, samp, hp.H)
+                _, opt_s2, state_s2, g, cands, losses = self._local_phase(
+                    params_s, opt_s, state_s if state_s else {}, (bx, by),
+                    ef_mem)
+                # non-participants sit the round out: their local state holds
+                # and their data stream is not consumed
+                opt_s = _where_clients(act, opt_s2, opt_s)
+                samp = _where_clients(act, samp2, samp)
+                if state_s:
+                    state_s = _where_clients(act, state_s2, state_s)
+                losses = jnp.where(act, losses, jnp.nan)
 
         # -- wire faults + validation gate (DESIGN.md §13) ------------------
         # ``act_ps`` is who the PS actually HEARS from this round: active
@@ -903,205 +915,208 @@ class FederatedEngine:
                 # gathered value/ef path below already consults
                 slot_ok = slot_ok & act_ps[iclip]
 
-        key, sub = jax.random.split(key)
-        method = hp.method
-        seg = None
-        if method == "rage_k":
-            # both selection planes consume the FUSED report (g=None):
-            # in gathered mode the compact (m, r) report was scattered
-            # into full-N layout above (inactive rows are never read)
-            if self._selection == "segmented":
-                idx, age, seg = rage_select_segmented(
-                    None, age, r=hp.r, k=hp.k, num_segments=num_segments,
-                    max_seg=max_seg, disjoint=hp.disjoint_in_cluster,
-                    impl=self._sel_impl, return_seg=True,
-                    candidates=hp.candidates, active=act_ps, cands=cands,
-                    d=d)
-            else:
-                idx, age = rage_select(None, age, r=hp.r, k=hp.k,
-                                       disjoint=hp.disjoint_in_cluster,
-                                       candidates=hp.candidates,
-                                       active=act_ps, cands=cands, d=d)
-        elif method == "cafe":
-            # per-client cost-and-age selection via the batched protocol;
-            # cluster_age doubles as the per-client age rows (clusters
-            # stay singleton — no recluster on this method) and the
-            # cumulative cost CAFe discounts by lives in ``freq``
-            # (dense layout) or the dedicated ``cost`` rows
-            # (hierarchical — already cluster-keyed, cafe clusters are
-            # singletons). Inactive clients: eq. (2) with no reset, no
-            # cost, no request
-            cost_pl = age.freq if age.freq is not None else age.cost
-            if gathered:
-                idx_c, _, (ca_c, fr_c) = self._strategy.select_batch(
-                    g, (age.cluster_age[iclip], cost_pl[iclip]))
-                ca = (age.cluster_age + 1).at[act_idx].set(ca_c,
-                                                           mode="drop")
-                fr = cost_pl.at[act_idx].set(fr_c, mode="drop")
-                if act_ps is not act:
-                    # quarantined/dropped rows: eq. (2) no reset, no cost
-                    ca = jnp.where(act_ps[:, None], ca,
-                                   age.cluster_age + 1)
+        with jax.named_scope("selection"):
+            key, sub = jax.random.split(key)
+            method = hp.method
+            seg = None
+            if method == "rage_k":
+                # both selection planes consume the FUSED report (g=None):
+                # in gathered mode the compact (m, r) report was scattered
+                # into full-N layout above (inactive rows are never read)
+                if self._selection == "segmented":
+                    idx, age, seg = rage_select_segmented(
+                        None, age, r=hp.r, k=hp.k, num_segments=num_segments,
+                        max_seg=max_seg, disjoint=hp.disjoint_in_cluster,
+                        impl=self._sel_impl, return_seg=True,
+                        candidates=hp.candidates, active=act_ps, cands=cands,
+                        d=d)
+                else:
+                    idx, age = rage_select(None, age, r=hp.r, k=hp.k,
+                                           disjoint=hp.disjoint_in_cluster,
+                                           candidates=hp.candidates,
+                                           active=act_ps, cands=cands, d=d)
+            elif method == "cafe":
+                # per-client cost-and-age selection via the batched protocol;
+                # cluster_age doubles as the per-client age rows (clusters
+                # stay singleton — no recluster on this method) and the
+                # cumulative cost CAFe discounts by lives in ``freq``
+                # (dense layout) or the dedicated ``cost`` rows
+                # (hierarchical — already cluster-keyed, cafe clusters are
+                # singletons). Inactive clients: eq. (2) with no reset, no
+                # cost, no request
+                cost_pl = age.freq if age.freq is not None else age.cost
+                if gathered:
+                    idx_c, _, (ca_c, fr_c) = self._strategy.select_batch(
+                        g, (age.cluster_age[iclip], cost_pl[iclip]))
+                    ca = (age.cluster_age + 1).at[act_idx].set(ca_c,
+                                                               mode="drop")
+                    fr = cost_pl.at[act_idx].set(fr_c, mode="drop")
+                    if act_ps is not act:
+                        # quarantined/dropped rows: eq. (2) no reset, no cost
+                        ca = jnp.where(act_ps[:, None], ca,
+                                       age.cluster_age + 1)
+                        fr = jnp.where(act_ps[:, None], fr, cost_pl)
+                    idx = jnp.full((n, hp.k), d, jnp.int32).at[act_idx].set(
+                        idx_c.astype(jnp.int32), mode="drop")
+                else:
+                    idx, _, (ca, fr) = self._strategy.select_batch(
+                        g, (age.cluster_age, cost_pl))
+                    ca = jnp.where(act_ps[:, None], ca, age.cluster_age + 1)
                     fr = jnp.where(act_ps[:, None], fr, cost_pl)
-                idx = jnp.full((n, hp.k), d, jnp.int32).at[act_idx].set(
-                    idx_c.astype(jnp.int32), mode="drop")
-            else:
-                idx, _, (ca, fr) = self._strategy.select_batch(
-                    g, (age.cluster_age, cost_pl))
-                ca = jnp.where(act_ps[:, None], ca, age.cluster_age + 1)
-                fr = jnp.where(act_ps[:, None], fr, cost_pl)
-                idx = idx.astype(jnp.int32)
-            if age.freq is not None:
-                age = age._replace(cluster_age=ca, freq=fr)
-            else:
-                age = age._replace(cluster_age=ca, cost=fr)
-        elif method == "dense":
-            idx = None
-        elif method in ("rtop_k", "random_k"):
-            # the per-client key split stays full-N so a client's key
-            # depends only on its id, not on who else took part
-            keys = jax.random.split(sub, self.n)
-            if gathered:
-                idx_c, _, _ = self._strategy.select_batch(g, keys[iclip])
-                idx = jnp.full((n, hp.k), d, jnp.int32).at[act_idx].set(
-                    idx_c.astype(jnp.int32), mode="drop")
-            else:
-                idx, _, _ = self._strategy.select_batch(g, keys)
-        else:                                     # top_k — deterministic
-            if gathered:
-                idx_c, _, _ = self._strategy.select_batch(g, ())
-                idx = jnp.full((n, hp.k), d, jnp.int32).at[act_idx].set(
-                    idx_c.astype(jnp.int32), mode="drop")
-            else:
-                idx, _, _ = self._strategy.select_batch(g, ())
+                    idx = idx.astype(jnp.int32)
+                if age.freq is not None:
+                    age = age._replace(cluster_age=ca, freq=fr)
+                else:
+                    age = age._replace(cluster_age=ca, cost=fr)
+            elif method == "dense":
+                idx = None
+            elif method in ("rtop_k", "random_k"):
+                # the per-client key split stays full-N so a client's key
+                # depends only on its id, not on who else took part
+                keys = jax.random.split(sub, self.n)
+                if gathered:
+                    idx_c, _, _ = self._strategy.select_batch(g, keys[iclip])
+                    idx = jnp.full((n, hp.k), d, jnp.int32).at[act_idx].set(
+                        idx_c.astype(jnp.int32), mode="drop")
+                else:
+                    idx, _, _ = self._strategy.select_batch(g, keys)
+            else:                                     # top_k — deterministic
+                if gathered:
+                    idx_c, _, _ = self._strategy.select_batch(g, ())
+                    idx = jnp.full((n, hp.k), d, jnp.int32).at[act_idx].set(
+                        idx_c.astype(jnp.int32), mode="drop")
+                else:
+                    idx, _, _ = self._strategy.select_batch(g, ())
 
-        if idx is not None:
-            # inactive clients request nothing — sentinel-d rows, in ONE
-            # place so no strategy branch can forget the mask (a no-op
-            # on the rage paths, which already masked internally).
-            # act_ps: quarantined/dropped clients request nothing either
-            idx = jnp.where(act_ps[:, None], idx, jnp.int32(d))
+            if idx is not None:
+                # inactive clients request nothing — sentinel-d rows, in ONE
+                # place so no strategy branch can forget the mask (a no-op
+                # on the rage paths, which already masked internally).
+                # act_ps: quarantined/dropped clients request nothing either
+                idx = jnp.where(act_ps[:, None], idx, jnp.int32(d))
 
-        if method == "rage_k" and age.log_ptr is not None:
-            # hierarchical layout: append this round's requests to the
-            # sparse update log ring (the every-M DBSCAN input — the
-            # dense layout's on-device freq scatter moved host-side).
-            # Rows are the compacted participants; padded slots carry
-            # sentinel client id n and all-sentinel-d index rows
-            if gathered:
-                mem, ok, mclip = act_idx, slot_ok, iclip
-            else:
-                mem = jnp.nonzero(act, size=age.log_mem.shape[1],
-                                  fill_value=n)[0].astype(jnp.int32)
-                ok = mem < n
-                mclip = jnp.minimum(mem, jnp.int32(n - 1))
-            slot = jax.lax.rem(age.log_ptr,
-                               jnp.int32(age.log_idx.shape[0]))
-            age = age._replace(
-                log_idx=age.log_idx.at[slot].set(
-                    jnp.where(ok[:, None], idx[mclip], jnp.int32(d))),
-                log_mem=age.log_mem.at[slot].set(mem),
-                log_ptr=age.log_ptr + 1)
-        if age.upload_cost is not None:
-            # O(N) per-client cumulative upload-cost scalar (entries
-            # actually uploaded this round — the CAFe-style cost signal
-            # at scale, no dense matrix needed)
-            per = jnp.int32(d if method == "dense" else hp.k)
-            age = age._replace(upload_cost=age.upload_cost
-                               + act.astype(jnp.int32) * per)
+            if method == "rage_k" and age.log_ptr is not None:
+                # hierarchical layout: append this round's requests to the
+                # sparse update log ring (the every-M DBSCAN input — the
+                # dense layout's on-device freq scatter moved host-side).
+                # Rows are the compacted participants; padded slots carry
+                # sentinel client id n and all-sentinel-d index rows
+                if gathered:
+                    mem, ok, mclip = act_idx, slot_ok, iclip
+                else:
+                    mem = jnp.nonzero(act, size=age.log_mem.shape[1],
+                                      fill_value=n)[0].astype(jnp.int32)
+                    ok = mem < n
+                    mclip = jnp.minimum(mem, jnp.int32(n - 1))
+                slot = jax.lax.rem(age.log_ptr,
+                                   jnp.int32(age.log_idx.shape[0]))
+                age = age._replace(
+                    log_idx=age.log_idx.at[slot].set(
+                        jnp.where(ok[:, None], idx[mclip], jnp.int32(d))),
+                    log_mem=age.log_mem.at[slot].set(mem),
+                    log_ptr=age.log_ptr + 1)
+            if age.upload_cost is not None:
+                # O(N) per-client cumulative upload-cost scalar (entries
+                # actually uploaded this round — the CAFe-style cost signal
+                # at scale, no dense matrix needed)
+                per = jnp.int32(d if method == "dense" else hp.k)
+                age = age._replace(upload_cost=age.upload_cost
+                                   + act.astype(jnp.int32) * per)
 
-        # ``sent`` (what each client actually uploaded, for the ef
-        # residual) stays COMPACT (m, d) in gathered mode; only the
-        # O(N*k) vals layout is rebuilt full-size for aggregation, so
-        # the sum's add order (client-ascending) matches the masked
-        # path's bit for bit
-        if idx is None:
-            if gathered:
-                gw = g.astype(self._wire_dtype).astype(g.dtype)
-                gw = jnp.where(
-                    stale[iclip][:, None],
-                    gw * plan.weight[iclip][:, None].astype(g.dtype), gw)
-                if act_ps is not act:
-                    # quarantined/dropped slots contribute nothing
-                    gw = jnp.where(slot_ok[:, None], gw,
+        with jax.named_scope("aggregation"):
+            # ``sent`` (what each client actually uploaded, for the ef
+            # residual) stays COMPACT (m, d) in gathered mode; only the
+            # O(N*k) vals layout is rebuilt full-size for aggregation, so
+            # the sum's add order (client-ascending) matches the masked
+            # path's bit for bit
+            if idx is None:
+                if gathered:
+                    gw = g.astype(self._wire_dtype).astype(g.dtype)
+                    gw = jnp.where(
+                        stale[iclip][:, None],
+                        gw * plan.weight[iclip][:, None].astype(g.dtype), gw)
+                    if act_ps is not act:
+                        # quarantined/dropped slots contribute nothing
+                        gw = jnp.where(slot_ok[:, None], gw,
+                                       jnp.zeros((), g.dtype))
+                    sent = gw
+                    g_sum = jnp.zeros((n, d), g.dtype).at[act_idx].set(
+                        gw, mode="drop").sum(0)
+                else:
+                    gw = g.astype(self._wire_dtype).astype(g.dtype)
+                    gw = jnp.where(
+                        stale[:, None],
+                        gw * plan.weight[:, None].astype(g.dtype), gw)
+                    gw = jnp.where(act_ps[:, None], gw,
                                    jnp.zeros((), g.dtype))
-                sent = gw
-                g_sum = jnp.zeros((n, d), g.dtype).at[act_idx].set(
-                    gw, mode="drop").sum(0)
+                    g_sum = gw.sum(0)
+                    sent = gw
             else:
-                gw = g.astype(self._wire_dtype).astype(g.dtype)
-                gw = jnp.where(
-                    stale[:, None],
-                    gw * plan.weight[:, None].astype(g.dtype), gw)
-                gw = jnp.where(act_ps[:, None], gw,
-                               jnp.zeros((), g.dtype))
-                g_sum = gw.sum(0)
-                sent = gw
-        else:
-            if gathered:
-                idx_rows = idx[iclip]
-                vals_c = jnp.take_along_axis(
-                    g, jnp.minimum(idx_rows, jnp.int32(d - 1)), axis=1)
-                vals_c = vals_c.astype(self._wire_dtype).astype(g.dtype)
-                vals_c = jnp.where(
-                    stale[iclip][:, None],
-                    vals_c * plan.weight[iclip][:, None].astype(g.dtype),
-                    vals_c)
-                vals_c = jnp.where(slot_ok[:, None], vals_c,
-                                   jnp.zeros((), g.dtype))
-                vals = jnp.zeros((n, idx.shape[1]), g.dtype).at[
-                    act_idx].set(vals_c, mode="drop")
-                sent = jax.vmap(
-                    lambda i, v: jnp.zeros((self.d,), g.dtype).at[i].set(
-                        v, mode="drop")
-                )(idx_rows, vals_c)
-            else:
-                vals = jnp.take_along_axis(
-                    g, jnp.minimum(idx, jnp.int32(d - 1)), axis=1)
-                vals = vals.astype(self._wire_dtype).astype(g.dtype)
-                # stale arrivals land staleness-discounted; the fresh
-                # path stays bitwise untouched (weight only where stale)
-                vals = jnp.where(
-                    stale[:, None],
-                    vals * plan.weight[:, None].astype(g.dtype), vals)
-                vals = jnp.where(act_ps[:, None], vals,
-                                 jnp.zeros((), g.dtype))
-                sent = jax.vmap(
-                    lambda i, v: jnp.zeros((self.d,), g.dtype).at[i].set(
-                        v, mode="drop")
-                )(idx, vals)
-            if seg is not None and self._agg_impl == "pallas":
-                # fused path: the SEGMENTED layout feeds the kernel
-                # directly — padded member slots (and, under a partial
-                # plan, unpacked inactive clients) carry the sentinel
-                # index d, which the scatter kernel drops
-                mclip = jnp.minimum(seg.members, self.n - 1)
-                seg_vals = jnp.where(seg.members[..., None] < self.n,
-                                     vals[mclip], jnp.zeros((), g.dtype))
-                dense, _ = aggregate_sparse_fused(
-                    seg.idx, seg_vals, jnp.zeros((self.d,), jnp.int32),
-                    impl="pallas")
-                g_sum = dense
-            else:
-                g_sum = self._aggregate(idx, vals)
-        if ef_mem is not None:
-            if gathered:
-                ef_rows = g - sent
-                if act_ps is not act:
-                    # wire-faulted slots hold their ef memory: the
-                    # corrupted row must not poison the residual
-                    ef_rows = jnp.where(slot_ok[:, None], ef_rows,
-                                        gather_rows(ef_mem))
-                ef_mem = ef_mem.at[act_idx].set(ef_rows, mode="drop")
-            else:
-                ef_new = g - sent
-                if act_ps is not act:
-                    ef_new = jnp.where(act_ps[:, None], ef_new, ef_mem)
-                ef_mem = jnp.where(act[:, None], ef_new, ef_mem)
+                if gathered:
+                    idx_rows = idx[iclip]
+                    vals_c = jnp.take_along_axis(
+                        g, jnp.minimum(idx_rows, jnp.int32(d - 1)), axis=1)
+                    vals_c = vals_c.astype(self._wire_dtype).astype(g.dtype)
+                    vals_c = jnp.where(
+                        stale[iclip][:, None],
+                        vals_c * plan.weight[iclip][:, None].astype(g.dtype),
+                        vals_c)
+                    vals_c = jnp.where(slot_ok[:, None], vals_c,
+                                       jnp.zeros((), g.dtype))
+                    vals = jnp.zeros((n, idx.shape[1]), g.dtype).at[
+                        act_idx].set(vals_c, mode="drop")
+                    sent = jax.vmap(
+                        lambda i, v: jnp.zeros((self.d,), g.dtype).at[i].set(
+                            v, mode="drop")
+                    )(idx_rows, vals_c)
+                else:
+                    vals = jnp.take_along_axis(
+                        g, jnp.minimum(idx, jnp.int32(d - 1)), axis=1)
+                    vals = vals.astype(self._wire_dtype).astype(g.dtype)
+                    # stale arrivals land staleness-discounted; the fresh
+                    # path stays bitwise untouched (weight only where stale)
+                    vals = jnp.where(
+                        stale[:, None],
+                        vals * plan.weight[:, None].astype(g.dtype), vals)
+                    vals = jnp.where(act_ps[:, None], vals,
+                                     jnp.zeros((), g.dtype))
+                    sent = jax.vmap(
+                        lambda i, v: jnp.zeros((self.d,), g.dtype).at[i].set(
+                            v, mode="drop")
+                    )(idx, vals)
+                if seg is not None and self._agg_impl == "pallas":
+                    # fused path: the SEGMENTED layout feeds the kernel
+                    # directly — padded member slots (and, under a partial
+                    # plan, unpacked inactive clients) carry the sentinel
+                    # index d, which the scatter kernel drops
+                    mclip = jnp.minimum(seg.members, self.n - 1)
+                    seg_vals = jnp.where(seg.members[..., None] < self.n,
+                                         vals[mclip], jnp.zeros((), g.dtype))
+                    dense, _ = aggregate_sparse_fused(
+                        seg.idx, seg_vals, jnp.zeros((self.d,), jnp.int32),
+                        impl="pallas")
+                    g_sum = dense
+                else:
+                    g_sum = self._aggregate(idx, vals)
+            if ef_mem is not None:
+                if gathered:
+                    ef_rows = g - sent
+                    if act_ps is not act:
+                        # wire-faulted slots hold their ef memory: the
+                        # corrupted row must not poison the residual
+                        ef_rows = jnp.where(slot_ok[:, None], ef_rows,
+                                            gather_rows(ef_mem))
+                    ef_mem = ef_mem.at[act_idx].set(ef_rows, mode="drop")
+                else:
+                    ef_new = g - sent
+                    if act_ps is not act:
+                        ef_new = jnp.where(act_ps[:, None], ef_new, ef_mem)
+                    ef_mem = jnp.where(act[:, None], ef_new, ef_mem)
 
-        g_params, g_opt_state = apply_global(
-            self._g_opt, self._unflatten, g_sum, g_params, g_opt_state)
-        params_s = C.broadcast_global(g_params, self.n)
+        with jax.named_scope("global_update"):
+            g_params, g_opt_state = apply_global(
+                self._g_opt, self._unflatten, g_sum, g_params, g_opt_state)
+            params_s = C.broadcast_global(g_params, self.n)
 
         # AoI bookkeeping + participation metrics (scalars; the per-chunk
         # pull stays O(N*k)). Client AoI: rounds since last heard from.
@@ -1131,15 +1146,16 @@ class FederatedEngine:
 
     def _eval_impl(self, params_s, state_s):
         accs = []
-        for i in range(self.n):
-            p_i = jax.tree_util.tree_map(lambda x: x[i], params_s)
-            s_i = (jax.tree_util.tree_map(lambda x: x[i], state_s)
-                   if state_s else self._state0)
-            xe, ye = self._eval_sets[i]
-            logits = self._predict(p_i, s_i, xe)
-            accs.append(jnp.mean(
-                (jnp.argmax(logits, -1) == ye).astype(jnp.float32)))
-        return jnp.stack(accs)
+        with jax.named_scope("eval"):
+            for i in range(self.n):
+                p_i = jax.tree_util.tree_map(lambda x: x[i], params_s)
+                s_i = (jax.tree_util.tree_map(lambda x: x[i], state_s)
+                       if state_s else self._state0)
+                xe, ye = self._eval_sets[i]
+                logits = self._predict(p_i, s_i, xe)
+                accs.append(jnp.mean(
+                    (jnp.argmax(logits, -1) == ye).astype(jnp.float32)))
+            return jnp.stack(accs)
 
     # ------------------------------------------------------------------
     # host control plane
@@ -1235,7 +1251,9 @@ class FederatedEngine:
         segmented-packing bounds ride along as STATIC jit arguments
         (chunk boundaries align to the recluster rounds where they
         change), pre-bound so the returned callable keeps the
-        (data, carry) signature."""
+        (data, carry) signature. A key not built before is counted in
+        ``retraces``: its first call compiles."""
+        ns, ms = self._seg_bounds()
         fn = self._chunks.get(length)
         if fn is None:
             def chunk(data, carry, num_segments, max_seg):
@@ -1245,8 +1263,31 @@ class FederatedEngine:
                     carry, None, length=length)
             fn = self._chunks[length] = jax.jit(
                 chunk, static_argnames=("num_segments", "max_seg"))
-        ns, ms = self._seg_bounds()
+            self.retraces["length"] += 1
+        elif (length, ns, ms) not in self._chunk_keys:
+            self.retraces["packing"] += 1
+        self._chunk_keys.add((length, ns, ms))
         return partial(fn, num_segments=ns, max_seg=ms)
+
+    def program_texts(self) -> list:
+        """The compiled HLO text of every chunk program built so far
+        (those at the current packing bounds first) and of the eval
+        program. A TPU trace's op events name each op's HLO instruction
+        but carry no ``op_name``; this text maps one to the other, and
+        so each op to its named scope. Lowered again from the same
+        functions and arguments, so a program that ran comes from JAX's
+        in-process cache as it ran. (JAX's persistent cache keys leave
+        named scopes out: an executable loaded from it may predate
+        them.)"""
+        carry = self._pack()
+        now = self._seg_bounds()
+        texts = [self._chunks[n].lower(self._data, carry, num_segments=ns,
+                                       max_seg=ms).compile().as_text()
+                 for n, ns, ms in sorted(self._chunk_keys,
+                                         key=lambda k: k[1:] != now)]
+        texts.append(self._eval.lower(self.params_s, self.state_s)
+                     .compile().as_text())
+        return texts
 
     def _bookkeep(self, n_active: int | None = None):
         """Per-round host accounting shared by both drivers. Uplink is
@@ -1292,12 +1333,10 @@ class FederatedEngine:
         "age_peak"} — the only per-round device->host traffic (O(N*k)
         plus five scalars). Inactive clients' idx rows hold the
         sentinel d ("no request")."""
-        t0 = time.perf_counter()
         ns, ms = self._seg_bounds()
         carry, metrics = self._round(self._data, self._pack(),
                                      num_segments=ns, max_seg=ms)
         jax.block_until_ready(metrics)
-        self.device_s += time.perf_counter() - t0
         self._unpack(carry)
         out = self._round_row(metrics)
         self._bookkeep(out["n_active"])
@@ -1360,8 +1399,9 @@ class FederatedEngine:
 
         def work():
             t0 = time.perf_counter()
-            out = _recluster_host_packed(age, eps, mp, freq=freq,
-                                         compact=compact)
+            with TraceAnnotation("fl.recluster.compute"):
+                out = _recluster_host_packed(age, eps, mp, freq=freq,
+                                             compact=compact)
             return out, time.perf_counter() - t0
 
         self._recluster_future = self._recluster_pool.submit(work)
@@ -1381,10 +1421,12 @@ class FederatedEngine:
         if self._recluster_future is not None:
             return
         t0 = time.perf_counter()
-        self._drain_freq_log()
-        new_ca, labels = _recluster_host_packed(
-            self.age, self.hp.eps, self.hp.min_pts, freq=self._freq_host,
-            compact=self._age_layout == "hierarchical")
+        with TraceAnnotation("fl.recluster.compute"):
+            self._drain_freq_log()
+            new_ca, labels = _recluster_host_packed(
+                self.age, self.hp.eps, self.hp.min_pts,
+                freq=self._freq_host,
+                compact=self._age_layout == "hierarchical")
         dt = time.perf_counter() - t0
         self.recluster_s += dt
         self.recluster_wait_s += dt
@@ -1411,7 +1453,8 @@ class FederatedEngine:
             return
         t0 = time.perf_counter()
         try:
-            (new_ca, labels), comp_s = fut.result()
+            with TraceAnnotation("fl.recluster.join"):
+                (new_ca, labels), comp_s = fut.result()
         except BaseException as e:
             # capture BEFORE raising: the first raise may be swallowed
             # (__del__, a driver's bare except) but every later label
@@ -1434,11 +1477,6 @@ class FederatedEngine:
         # labels DBSCAN just produced ON HOST, no new device->host pull
         self._num_seg = int(labels.max()) + 1
         self._max_seg = int(np.bincount(labels).max())
-
-    @property
-    def recluster_hidden_s(self) -> float:
-        """Host clustering wall hidden behind chunk-boundary work."""
-        return max(0.0, self.recluster_s - self.recluster_wait_s)
 
     def close(self):
         """Join any in-flight recluster and release its worker thread.
@@ -1481,11 +1519,8 @@ class FederatedEngine:
         return self._scheduler
 
     def eval_acc(self) -> float:
-        t0 = time.perf_counter()
-        accs = self._eval(self.params_s, self.state_s)
-        jax.block_until_ready(accs)
-        self.device_s += time.perf_counter() - t0
-        return float(jnp.mean(accs))
+        with TraceAnnotation("fl.eval"):
+            return float(jnp.mean(self._eval(self.params_s, self.state_s)))
 
     def _record(self, res: FLResult, losses, *, end: int, eval_every: int,
                 heatmap_at, verbose: bool) -> None:
@@ -1550,6 +1585,47 @@ class FederatedEngine:
         stops.extend(h for h in heatmap_at if h > t)
         return min(stops)
 
+    def _scan_chunk(self, res: FLResult, T: int, *, end: int,
+                    eval_every: int, heatmap_at, verbose: bool,
+                    checkpointer, ckpt_every: int) -> None:
+        """One chunk of :meth:`run_scanned`: dispatch, the wait, and the
+        host stop at its end, each under a profiler span (``fl.*``)."""
+        # the bounds join an in-flight recluster; a key the chunk cache
+        # has not built compiles inside the dispatch (``fl.retrace``)
+        fresh = (T, *self._seg_bounds()) not in self._chunk_keys
+        with TraceAnnotation("fl.retrace" if fresh else "fl.dispatch"):
+            carry, metrics = self._chunk(T)(self._data, self._pack())
+        with TraceAnnotation("fl.device_wait"):
+            jax.block_until_ready(metrics)
+        with TraceAnnotation("fl.host_stop"):
+            self._unpack(carry)
+            # chunk boundaries align to the every-M recluster, so only
+            # the chunk's FINAL round can trigger one — kick the host
+            # DBSCAN onto the worker thread now and let it overlap the
+            # metrics drain + bookkeeping below; _recluster() joins it
+            # before anything reads the new labels
+            if (self.hp.method == "rage_k"
+                    and (self.round_idx + T) % self.hp.M == 0):
+                self._recluster_submit()
+            # the ONE per-chunk host pull: (T, N) losses, (T, N, k)
+            # indices, (T,)-stacked participation scalars
+            with TraceAnnotation("fl.metrics_pull"):
+                metrics = {k: np.asarray(v) for k, v in metrics.items()}
+            losses = metrics["losses"]
+            idx = metrics["idx"] if self.hp.method != "dense" else None
+            with TraceAnnotation("fl.bookkeep"):
+                for j in range(T):
+                    row = self._round_row(metrics, j)
+                    self._bookkeep(row["n_active"])
+                    self._track(res, row,
+                                idx[j] if idx is not None else None)
+            self._record(res, losses[-1], end=end, eval_every=eval_every,
+                         heatmap_at=heatmap_at, verbose=verbose)
+            if (checkpointer is not None and ckpt_every
+                    and self.round_idx % ckpt_every == 0):
+                with TraceAnnotation("fl.checkpoint"):
+                    self.save_state(checkpointer, result=res)
+
     def run_scanned(self, rounds: int, *, eval_every: int = 5,
                     heatmap_at=(), verbose: bool = False,
                     checkpointer=None, ckpt_every: int = 0,
@@ -1567,32 +1643,11 @@ class FederatedEngine:
         while self.round_idx < end:
             T = (self._next_stop(end, eval_every, heatmap_at, ckpt_every)
                  - self.round_idx)
-            td = time.perf_counter()
-            carry, metrics = self._chunk(T)(self._data, self._pack())
-            jax.block_until_ready(metrics)
-            self.device_s += time.perf_counter() - td
-            self._unpack(carry)
-            # chunk boundaries align to the every-M recluster, so only
-            # the chunk's FINAL round can trigger one — kick the host
-            # DBSCAN onto the worker thread now and let it overlap the
-            # metrics drain + bookkeeping below; _recluster() joins it
-            # before anything reads the new labels
-            if (self.hp.method == "rage_k"
-                    and (self.round_idx + T) % self.hp.M == 0):
-                self._recluster_submit()
-            # the ONE per-chunk host pull: (T, N) losses, (T, N, k)
-            # indices, (T,)-stacked participation scalars
-            metrics = {k: np.asarray(v) for k, v in metrics.items()}
-            losses = metrics["losses"]
-            idx = metrics["idx"] if self.hp.method != "dense" else None
-            for j in range(T):
-                row = self._round_row(metrics, j)
-                self._bookkeep(row["n_active"])
-                self._track(res, row, idx[j] if idx is not None else None)
-            self._record(res, losses[-1], end=end, eval_every=eval_every,
-                         heatmap_at=heatmap_at, verbose=verbose)
-            if (checkpointer is not None and ckpt_every
-                    and self.round_idx % ckpt_every == 0):
-                self.save_state(checkpointer, result=res)
+            with TraceAnnotation("fl.chunk", round=self.round_idx,
+                                 rounds=T):
+                self._scan_chunk(res, T, end=end, eval_every=eval_every,
+                                 heatmap_at=heatmap_at, verbose=verbose,
+                                 checkpointer=checkpointer,
+                                 ckpt_every=ckpt_every)
         res.wall_s = time.time() - t0
         return res

@@ -347,7 +347,6 @@ class AsyncService:
         self.cum_downlink = self._downlink_per_dispatch * self.n  # t=0 fleet
         self.aggs_done = 0
         self.events_done = 0
-        self.device_s = 0.0
         self.recluster_s = 0.0
 
     # ------------------------------------------------------------------
@@ -620,10 +619,7 @@ class AsyncService:
         return the stacked (n_events, ...) metrics as numpy. The carry
         round-trips through ``self.state``, so ANY chunking of the same
         total event count replays the identical event sequence."""
-        t0 = time.perf_counter()
         st, metrics = self._chunk(n_events)(self._data, self.state)
-        jax.block_until_ready(metrics["loss"])
-        self.device_s += time.perf_counter() - t0
         self.state = st
         self.events_done += n_events
         return {k: np.asarray(v) for k, v in metrics.items()}
@@ -725,10 +721,7 @@ class AsyncService:
         self._log_seen = int(ex["log_seen"])
 
     def eval_acc(self) -> float:
-        t0 = time.perf_counter()
         accs = self._eval(self.state.g_params, self.state.state_s)
-        jax.block_until_ready(accs)
-        self.device_s += time.perf_counter() - t0
         return float(jnp.mean(accs))
 
     @property

@@ -10,6 +10,7 @@ import argparse
 import json
 import os
 
+import jax
 import numpy as np
 
 from repro.checkpoint import AsyncCheckpointer
@@ -174,6 +175,12 @@ def build_parser() -> argparse.ArgumentParser:
                          "the first checkpoint at/past this round "
                          "commits (requires --ckpt-dir and "
                          "--ckpt-every)")
+    ap.add_argument("--profile-dir", default=None,
+                    help="write a profiler trace of the whole run here "
+                         "(jax.profiler.trace): the engine's fl.* host "
+                         "spans and the round's named scopes, with the "
+                         "compiled programs' HLO text (program*.hlo.txt) "
+                         "that maps a TPU op to its scope")
     return ap
 
 
@@ -235,7 +242,18 @@ def build_engine(args) -> FederatedEngine:
 
 def main():
     args = build_parser().parse_args()
-    use_compile_cache()
+    if not args.profile_dir:
+        use_compile_cache()
+        _train(args)
+        return
+    # JAX's persistent cache keys leave named scopes out, so a cached
+    # executable may predate them: a profiled run compiles afresh
+    jax.config.update("jax_enable_compilation_cache", False)
+    with jax.profiler.trace(args.profile_dir):
+        _train(args)
+
+
+def _train(args):
     ck = None
     if args.ckpt_dir:
         ck = (_KillingCheckpointer(args.ckpt_dir, args.kill_at_round)
@@ -297,10 +315,19 @@ def main():
                 eval_every=max(args.rounds // 20, 1),
                 heatmap_at=(1, args.rounds), verbose=True,
                 checkpointer=ck, ckpt_every=args.ckpt_every, result=prior)
+    if args.profile_dir and args.driver == "scan":
+        # a TPU trace's op events carry no op_name: these texts map each
+        # op to its named scope
+        for i, text in enumerate(engine.program_texts()):
+            with open(os.path.join(args.profile_dir,
+                                   f"program{i}.hlo.txt"), "w") as f:
+                f.write(text)
     engine.close()
     if ck is not None:
         ck.close()
     print("summary:", res.summary())
+    if args.driver == "scan":
+        print("chunk programs built:", engine.retraces)
     print("final clusters:", res.cluster_labels[-1].tolist())
     if args.out:
         with open(args.out, "w") as f:
