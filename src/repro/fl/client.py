@@ -1,7 +1,9 @@
 """Client-side machinery: vmapped local training phases (Algorithm 1,
 lines 3-5). All N clients advance H local Adam steps inside one jitted
 scan; the LAST local gradient is returned flat for sparsification (line 7
-applies rAge-k to the gradient at the global-iteration step).
+applies rAge-k to the gradient at the global-iteration step). Only that
+gradient is kept: it rides in the scan's carry, each step replacing it,
+so the phase never stacks H gradient trees (an (H, N, d) buffer).
 
 Both phases can FUSE the protocol's client-side tail into the same
 program (DESIGN.md §11): error-feedback add (``g + ef``) and the top-r
@@ -57,7 +59,9 @@ def make_client_phase(apply_loss: Callable, lr: float, *,
     opt_state, state, flat_last_grad (d,), mean_loss ()); batches is an
     (H, ...) pytree. :func:`make_local_phase` is exactly its vmap, so a
     single-client call is bitwise the corresponding row of the batched
-    phase (pinned by tests/test_service.py).
+    phase (pinned by tests/test_service.py). The scan carries the newest
+    gradient tree and emits only each step's loss, so memory does not
+    grow with H.
 
     ``ef`` (optional) is the client's (d,) error-feedback residual,
     added to the flat gradient in-phase. ``report_r`` fuses the top-r
@@ -68,17 +72,17 @@ def make_client_phase(apply_loss: Callable, lr: float, *,
     opt = adam(lr)
 
     def one_step(carry, batch):
-        params, opt_state, state = carry
+        params, opt_state, state, _ = carry
         (loss, new_state), grads = jax.value_and_grad(
             apply_loss, has_aux=True)(params, state, batch)
         updates, opt_state = opt.update(grads, opt_state, params)
         params = apply_updates(params, updates)
-        return (params, opt_state, new_state), (loss, grads)
+        return (params, opt_state, new_state, grads), loss
 
     def phase_one_client(params, opt_state, state, batches, ef=None):
-        (params, opt_state, state), (losses, grads_seq) = jax.lax.scan(
-            one_step, (params, opt_state, state), batches)
-        last_grad = jax.tree_util.tree_map(lambda g: g[-1], grads_seq)
+        grads0 = jax.tree_util.tree_map(jnp.zeros_like, params)
+        (params, opt_state, state, last_grad), losses = jax.lax.scan(
+            one_step, (params, opt_state, state, grads0), batches)
         g = flatten_tree(last_grad)
         if ef is not None:
             g = g + ef

@@ -627,7 +627,8 @@ class FederatedEngine:
                                        lam=hp.cafe_lam,
                                        candidates=hp.candidates)
         # rage_k fuses the top-r candidate report into the local phase's
-        # last step (DESIGN.md §11): the report comes out of the SAME
+        # tail, on the last step's gradient that the phase carries
+        # through its scan (DESIGN.md §11): the report comes out of the SAME
         # batched client_candidates call selection would have made on
         # the same post-ef gradients, so the (N, d) grad matrix is
         # never re-materialized for the selection plane — and the fused
