@@ -10,7 +10,10 @@ cell against the plain reference (the lower readings). For each
 program's place (the upper readings). For each ``--fault-seeds`` seed: the
 reference with a fault planted, in the program's place: half of each batch
 left out of the loss, and every requested index moved to its neighbour
-where selection produces it. For each ``--witness-seeds`` seed: the
+where selection produces it; where the cell's clients take part in turns,
+also each round's participants moved to the next client id, the ages of a
+client that sits a round out reset at the round's picks, and its Adam
+first moment decayed as by a step. For each ``--witness-seeds`` seed: the
 reference with its products at the default precision (one bfloat16 pass,
 as the program's) in the program's place, a second sound run that shows
 how far two precisions part on their own. Every reading is judged against
@@ -25,9 +28,12 @@ import json
 import sys
 import time
 
+import jax
 import jax.numpy as jnp
+import numpy as np
 
 import run  # sets the import paths and the chip's environment
+from reference import B1
 
 harness = run.harness
 compare = run.compare
@@ -45,11 +51,47 @@ class HalfBatch(harness.Reference):
 class AlteredPicks(harness.Reference):
     """The reference with every requested index moved to its neighbour."""
 
-    def select(self, cands):
-        return (super().select(cands) + 1) % self.d
+    def select(self, cands, ids):
+        picks = super().select(cands, ids)
+        return np.where(picks < self.d, (picks + 1) % self.d, picks)
+
+
+class ShiftedPlan(harness.Reference):
+    """Each round's participants moved to the next client id."""
+
+    def plan(self):
+        return np.roll(super().plan(), 1)
+
+
+class IdleAgesReset(harness.Reference):
+    """The cluster ages of a client that sits the round out reset at the
+    round's picks."""
+
+    def select(self, cands, ids):
+        picks = super().select(cands, ids)
+        picked = np.unique(picks[picks < self.d])
+        for i in sorted(set(range(self.n)) - set(ids.tolist())):
+            self.ages[int(self.cluster_of[i])][picked] = 0
+        return picks
+
+
+class IdleAdamStep(harness.Reference):
+    """The Adam first moment of a client that sits the round out decays as
+    by a step with no gradient."""
+
+    def step(self):
+        idle = np.nonzero(~self.plan())[0]
+        super().step()
+        for i in idle:
+            count, mu, nu = self.client_opt[i]
+            self.client_opt[i] = (count, jax.tree_util.tree_map(
+                lambda m: m * B1, mu), nu)
 
 
 FAULTS = {"half_batch": HalfBatch, "picks_altered": AlteredPicks}
+PARTICIPATION_FAULTS = {"plan_shifted": ShiftedPlan,
+                        "idle_ages_reset": IdleAgesReset,
+                        "idle_adam_step": IdleAdamStep}
 
 
 def main(argv=None):
@@ -79,8 +121,11 @@ def main(argv=None):
         emit("program", seed, t, numbers, worst, phases=r.phases)
     stand_ins = {"control": (args.control_seeds, dict(dtype=jnp.bfloat16)),
                  "witness": (args.witness_seeds, dict(precision="default"))}
+    faults = dict(FAULTS)
+    if harness.protocol(cell).get("schedule", "full") != "full":
+        faults.update(PARTICIPATION_FAULTS)
     stand_ins.update({name: (args.fault_seeds, dict(make=make))
-                      for name, make in FAULTS.items()})
+                      for name, make in faults.items()})
     for seed in sorted({s for seeds, _ in stand_ins.values() for s in seeds}):
         shards, _ = harness.synth.federation_data(cell["config"], seed)
         ref = harness.follow(cell, shards, seed)

@@ -3,12 +3,17 @@ checked rounds against the reference's.
 
 Each is a gap that is 0 where the two agree exactly:
 
-- ``loss_gap``: relative gap of the clients' mean training loss at each
-  checked evaluation round, the largest;
+- ``loss_gap``: relative gap of the participants' mean training loss at
+  each checked evaluation round, the largest (both sides report NaN for a
+  client that sat the round out);
 - ``local_state_gap``: the clients' Adam first moments after the checked
   rounds, by the worst leaf of the worst client: the gap between the
   program's norm and the reference's, over the larger of the reference's
-  norm of that leaf and of its median leaf;
+  norm of that leaf and of its median leaf. Every client counts, so a
+  client whose state should have been held and moved fails; one that has
+  not yet taken part, whose reference moments are all zero, reads the
+  program's worst leaf norm over the median of the trained clients' median
+  leaf norms (0 where the program's are zero too);
 - ``global_grad_gap``: the same for the global Adam first moment, the
   aggregated gradients as the server's optimizer holds them;
 - ``global_change_gap``: the same for the change of the global
@@ -16,7 +21,8 @@ Each is a gap that is 0 where the two agree exactly:
   the median over the leaves of that leaf gap (steadier where the picks
   of the two sides part, so that which coordinates a leaf moved differs);
 - ``pick_mismatch``: share of the requested (round, client, index)
-  entries that the reference did not request;
+  entries that the reference did not request (the sentinel d of a client
+  that sat out is no request);
 - ``age_mismatch``: share of the per-client age entries that differ, among
   the coordinates that either side ever requested;
 - ``cluster_mismatch``: client pairs whose co-membership differs after the
@@ -79,12 +85,39 @@ def co_membership(labels: np.ndarray) -> np.ndarray:
     return labels[:, None] == labels[None, :]
 
 
+def participant_mean(losses) -> float:
+    """Mean loss of a round's participants (the finite entries)."""
+    losses = np.asarray(losses)
+    return float(np.mean(losses[np.isfinite(losses)]))
+
+
+def client_gaps(prog_mu: list, ref_mu: list) -> list:
+    """Each client's leaf gap of its Adam first moment. A client whose
+    reference moments are all zero has not yet trained: its gap is the
+    program's worst leaf norm over the scale of a trained client's moment
+    (the median over those clients of their median counted leaf norm)."""
+    rows = [leaf_gaps(p, r, r) for p, r in zip(prog_mu, ref_mu)]
+    scale = np.median([np.median(r[keep]) for _, keep, _, r in rows
+                       if keep.any()] or [0.0])
+    out = []
+    for gap, keep, p, r in rows:
+        if r.any():
+            out.append(float(np.max(gap[keep])) if keep.any() else 0.0)
+        else:
+            worst = p.max(initial=0.0)
+            out.append(float(worst / scale) if worst else 0.0)
+    return out
+
+
 def explain(prog, ref) -> dict:
     """The worst leaf behind each leaf gap (for calibration)."""
-    worst = max(range(len(ref.client_mu)), key=lambda i: leaf_gap(
-        prog.client_mu[i], ref.client_mu[i], ref.client_mu[i]))
-    local = worst_leaf(prog.client_mu[worst], ref.client_mu[worst],
-                       ref.client_mu[worst])
+    per_client = client_gaps(prog.client_mu, ref.client_mu)
+    worst = int(np.argmax(per_client))
+    if _norms(ref.client_mu[worst]).any():
+        local = worst_leaf(prog.client_mu[worst], ref.client_mu[worst],
+                           ref.client_mu[worst])
+    else:
+        local = {"untrained": True, "gap": per_client[worst]}
     local["client"] = worst
     return {"local_state_gap": local,
             "global_grad_gap": worst_leaf(prog.global_mu, ref.global_mu,
@@ -104,21 +137,22 @@ def gaps(prog, ref, eval_rounds: list, prog_losses: list) -> dict:
     ``prog_losses[j]`` is the program's mean loss at round
     ``eval_rounds[j]``."""
     out = {}
-    ref_loss = [float(np.mean(ref.losses[t - 1])) for t in eval_rounds]
+    ref_loss = [participant_mean(ref.losses[t - 1]) for t in eval_rounds]
     out["loss_gap"] = max(abs(p - r) / abs(r)
                           for p, r in zip(prog_losses, ref_loss))
-    out["local_state_gap"] = max(
-        leaf_gap(p, r, r) for p, r in zip(prog.client_mu, ref.client_mu))
+    out["local_state_gap"] = max(client_gaps(prog.client_mu, ref.client_mu))
     out["global_grad_gap"] = leaf_gap(prog.global_mu, ref.global_mu,
                                       ref.global_mu)
     out["global_change_gap"] = leaf_gap(_change(prog), _change(ref),
                                         ref.global_mu)
     out["global_change_median_gap"] = leaf_gap(
         _change(prog), _change(ref), ref.global_mu, over=np.median)
-    missed = sum(np.setdiff1d(p_i, r_i).size
+    d = ref.freq.shape[1]
+    missed = sum(np.setdiff1d(p_i[p_i < d], r_i).size
                  for p, r in zip(prog.picks, ref.picks)
                  for p_i, r_i in zip(p, r))
-    out["pick_mismatch"] = missed / sum(np.size(p) for p in ref.picks)
+    out["pick_mismatch"] = missed / sum(int(np.sum(r < d))
+                                        for r in ref.picks)
     seen = (prog.freq > 0) | (ref.freq > 0)
     out["age_mismatch"] = (float(np.sum((prog.client_ages != ref.client_ages)
                                         & seen)) / max(int(seen.sum()), 1))

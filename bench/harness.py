@@ -32,7 +32,7 @@ import synth
 import trace_reduce
 import work
 from peaks import peak
-from reference import Reference, Trajectory, load_module
+from reference import Reference, Trajectory, check_plan, load_module
 
 BENCH = Path(__file__).resolve().parent
 ROOT = BENCH.parent
@@ -103,7 +103,10 @@ def split_clients(tree, n: int) -> list:
 
 
 def snapshot(engine, picks: list, params0) -> Trajectory:
-    """The program's trajectory so far, copied to the host."""
+    """The program's trajectory so far, copied to the host. The request
+    counts come from ``freq_matrix``, which either age layout gives (the
+    hierarchical one keeps them on the host); the age rows are keyed by
+    cluster in both, so a client's ages are its cluster's row."""
     labels = engine.cluster_of
     return Trajectory(
         picks=list(picks), labels=[labels], params0=params0,
@@ -111,7 +114,7 @@ def snapshot(engine, picks: list, params0) -> Trajectory:
         global_mu=jax.device_get(engine.g_opt_state.mu),
         client_mu=split_clients(jax.device_get(engine.opt_s.mu), engine.n),
         client_ages=np.asarray(engine.age.cluster_age)[labels],
-        freq=np.asarray(engine.age.freq))
+        freq=np.array(engine.freq_matrix))
 
 
 class CompileCounter:
@@ -139,6 +142,7 @@ class Run:
         self.cell, self.seed, self.t0 = cell, seed, t0
         self.mix = cell["mix"]
         self.proto = protocol(cell)
+        check_plan(self.proto)
         self.every = self.mix["eval_every"]
         M = self.proto["M"]
         if M % self.every and self.every % M:
@@ -198,23 +202,27 @@ class Run:
     # -- traced window -----------------------------------------------------
     def traced(self) -> tuple:
         """``trace_calls`` calls under the profiler: (reduced trace, rounds,
-        recluster wait in the window)."""
+        recluster wait in the window, participants summed over its
+        rounds)."""
         calls = self.mix["trace_calls"]
         wait0 = self.engine.recluster_wait_s
-        rounds = 0
+        rounds = active = 0
         self.compiles.on = True
         with tempfile.TemporaryDirectory(prefix="bench-trace-") as d:
             jax.profiler.start_trace(d)
             try:
                 with jax.profiler.TraceAnnotation("bench.window"):
                     for _ in range(calls):
-                        rounds += len(self._call().n_active)
+                        n_active = self._call().n_active
+                        rounds += len(n_active)
+                        active += sum(n_active)
             finally:
                 jax.profiler.stop_trace()
             self.compiles.on = False
             summary = trace_reduce.reduce(trace_reduce.load_xplane(d),
                                           "bench.window")
-        return summary, rounds, self.engine.recluster_wait_s - wait0
+        return (summary, rounds, self.engine.recluster_wait_s - wait0,
+                active)
 
     def memory_stats(self) -> dict:
         return jax.devices()[0].memory_stats() or {}
@@ -227,9 +235,11 @@ class Run:
         self.engine.close()
         del self.engine
         gc.collect()
+        t = time.perf_counter()
         ref = follow(self.cell, self.shards, self.seed)
         numbers = compare.gaps(self.prog, ref, checked_evals(self.cell),
                                self.check_losses)
+        self.check_s = time.perf_counter() - t
         return (numbers, compare.explain(self.prog, ref)) if explain \
             else numbers
 
@@ -257,7 +267,7 @@ def stand_in_gaps(cell: dict, got: Trajectory, ref: Trajectory,
     """The numbers of a reference run put in the program's place (the
     control, a witness, a planted fault) against the reference."""
     rounds = checked_evals(cell)
-    losses = [float(got.losses[t - 1].mean()) for t in rounds]
+    losses = [compare.participant_mean(got.losses[t - 1]) for t in rounds]
     numbers = compare.gaps(got, ref, rounds, losses)
     return (numbers, compare.explain(got, ref)) if explain else numbers
 
@@ -273,15 +283,22 @@ def control_gaps(cell: dict, shards: list, seed: int,
 
 
 def per_layer(cell: dict, summary, rounds: int, stall_s: float,
-              device_kind: str) -> dict:
+              device_kind: str, active: int | None = None) -> dict:
     """Each per-layer metric of the cell that its reader finds something
-    to read for, with its unit."""
+    to read for, with its unit. ``active`` is the participants summed over
+    the window's rounds (every client in every round where None): the
+    readers' ``n`` is the participants of a round, whose gradients the
+    report and the aggregation handle, and ``samples`` counts the batches
+    they train at the batch size the shards allow."""
     proto = protocol(cell)
     cfg = cell["config"]
-    n = len(cfg["clients"])
+    if active is None:
+        active = rounds * len(cfg["clients"])
+    bs = min(proto["batch_size"], min(synth.shard_lengths(cfg)))
     ctx = {"trace": summary, "rounds": rounds, "config": cfg,
-           "protocol": proto, "n": n, "d": cfg["n_params"],
-           "samples": rounds * n * proto["H"] * proto["batch_size"],
+           "protocol": proto, "d": cfg["n_params"],
+           "n": active / rounds if rounds else len(cfg["clients"]),
+           "samples": active * proto["H"] * bs,
            "recluster_wait_s": stall_s, "work": work,
            "peak": peak(device_kind) if summary else None}
     out = {}

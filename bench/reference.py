@@ -3,17 +3,25 @@
 It follows the rAge-k protocol of the paper (arXiv 2410.22192, Algorithm 1)
 from the seed, in straightforward JAX and NumPy, one client at a time:
 
-1. each client draws its next H batches from its own shuffled stream and
-   takes H Adam steps from the global parameters (its Adam moments and
+0. the round's participants are drawn by the configuration's ``schedule``:
+   ``full`` takes every client; ``uniform`` takes m of the N clients (m is
+   ``participation_m``, or max(N // 4, 1) where that is 0): the first m of
+   ``permutation(fold_in(PRNGKey(seed + SCHEDULE_KEY), round), N)``, the
+   round counted from 0. Participants are taken in ascending client id;
+1. each participant draws its next H batches from its own shuffled stream
+   and takes H Adam steps from the global parameters (its Adam moments and
    batch-norm statistics persist across rounds); its gradient at the last
-   step is its update;
+   step is its update. A client that sits a round out trains nothing: its
+   moments, statistics and stream stay as they were, and its loss is NaN;
 2. it reports the indices of its r largest gradient magnitudes;
 3. the parameter server picks, in client order, the k candidates of
-   highest age in the client's cluster, skipping those already picked for
-   the cluster this round, reading the ages of the round's start; then,
-   member by member, the cluster's age vector grows by one and the picked
-   coordinates reset to 0 (eq. 2), and the picks are counted (eq. 3
-   inputs);
+   highest age in the participant's cluster, skipping those already picked
+   for the cluster this round, reading the ages of the round's start; then
+   each cluster's age vector grows by one for every member that sat the
+   round out (eq. 2 for a member not heard from: no reset), and, member by
+   member over the participants, grows by one and the picked coordinates
+   reset to 0 (eq. 2); the picks are counted (eq. 3 inputs). A client that
+   sits out requests nothing: its row of picks holds the sentinel d;
 4. the picked values are summed into a dense gradient and the global model
    takes one Adam step;
 5. every M rounds, the clients are clustered by DBSCAN on the symmetrised
@@ -40,7 +48,9 @@ import numpy as np
 B1, B2, EPS = 0.9, 0.999, 1e-8
 
 # the program's PRNG streams, as offsets from the run's seed
-MODEL_KEY, SAMPLER_KEY = 0, 17
+MODEL_KEY, SAMPLER_KEY, SCHEDULE_KEY = 0, 17, 23
+# the participation plans the reference follows
+SCHEDULES = ("full", "uniform")
 
 
 def load_module(path: Path, name: str):
@@ -175,8 +185,10 @@ def regroup(ages: dict, old: np.ndarray, new: np.ndarray, d: int) -> dict:
 @dataclass
 class Trajectory:
     """What the reference (or the program) did over its rounds."""
-    losses: list = field(default_factory=list)     # per round, (N,)
-    picks: list = field(default_factory=list)      # per round, (N, k)
+    losses: list = field(default_factory=list)     # per round, (N,), NaN
+    #                                                for who sat it out
+    picks: list = field(default_factory=list)      # per round, (N, k), d
+    #                                                for who sat it out
     labels: list = field(default_factory=list)     # per recluster, (N,)
     params0: dict | None = None                    # global, before round 1
     params: dict | None = None                     # global, after the rounds
@@ -186,13 +198,36 @@ class Trajectory:
     freq: np.ndarray | None = None                 # (N, d) request counts
 
 
+def check_plan(proto: dict) -> None:
+    """Refuse a participation plan the reference does not follow, so that
+    no cell is checked against one."""
+    schedule = proto.get("schedule", "full")
+    if schedule not in SCHEDULES:
+        raise NotImplementedError(
+            f"the reference follows the schedules {SCHEDULES}, not "
+            f"{schedule!r}")
+
+
+def participants(proto: dict, n: int, seed: int, rnd: int) -> np.ndarray:
+    """(N,) bool: who takes part in round ``rnd`` (counted from 0)."""
+    check_plan(proto)
+    if proto.get("schedule", "full") == "full":
+        return np.ones(n, bool)
+    m = proto.get("participation_m", 0) or max(n // 4, 1)
+    key = jax.random.fold_in(jax.random.PRNGKey(seed + SCHEDULE_KEY), rnd)
+    active = np.zeros(n, bool)
+    active[np.asarray(jax.random.permutation(key, n))[:m]] = True
+    return active
+
+
 class Reference:
     """The federation of one configuration, followed from the seed."""
 
     def __init__(self, cfg: dict, proto: dict, model, shards: list,
                  seed: int, dtype=jnp.float32, precision: str = "highest"):
+        check_plan(proto)
         self.cfg, self.p, self.model, self.dtype = cfg, proto, model, dtype
-        self.precision = precision
+        self.seed, self.precision = seed, precision
         self.n = len(shards)
         lengths = [len(y) for _, y in shards]
         self.lengths = jnp.asarray(lengths, jnp.int32)
@@ -262,30 +297,43 @@ class Reference:
         return adam_step(params, opt, unflatten(dense, params), self.p["lr"],
                          self.dtype)
 
-    def select(self, cands: np.ndarray) -> np.ndarray:
-        """Step 3: the (N, k) picks, then eq. (2) and the request counts."""
+    def select(self, cands: np.ndarray, ids: np.ndarray) -> np.ndarray:
+        """Step 3 for the round's participants ``ids`` (ascending), whose
+        reports are the rows of ``cands``: the (N, k) picks, sentinel d in
+        the rows of those who sat out, then eq. (2) and the request
+        counts."""
         k = self.p["k"]
         taken: dict = {}
-        picks = np.zeros((self.n, k), np.int64)
-        for i in range(self.n):
+        picks = np.full((self.n, k), self.d, np.int64)
+        for i, cand in zip(ids, cands):
             c = int(self.cluster_of[i])
-            ages = self.ages[c][cands[i]].astype(np.int64)
+            ages = self.ages[c][cand].astype(np.int64)
             if self.p["disjoint_in_cluster"] and c in taken:
-                ages[np.isin(cands[i], taken[c])] = -1
-            picks[i] = cands[i][np.argsort(-ages, kind="stable")[:k]]
+                ages[np.isin(cand, taken[c])] = -1
+            picks[i] = cand[np.argsort(-ages, kind="stable")[:k]]
             taken[c] = np.concatenate([taken.get(c, picks[i][:0]), picks[i]])
-        # every pick above read the ages of the round's start
-        for i in range(self.n):
+        # every pick above read the ages of the round's start; a member
+        # that sat out adds its one with no reset, before the participants
+        idle = np.ones(self.n, bool)
+        idle[ids] = False
+        for i in np.nonzero(idle)[0]:
+            self.ages[int(self.cluster_of[i])] += 1
+        for i in ids:
             a = self.ages[int(self.cluster_of[i])]
             a += 1
             a[picks[i]] = 0
             self.freq[i, picks[i]] += 1
         return picks
 
+    def plan(self) -> np.ndarray:
+        """(N,) bool: this round's participants."""
+        return participants(self.p, self.n, self.seed, self.round)
+
     def step(self):
         """One global round."""
+        ids = np.nonzero(self.plan())[0]
         grads, reports, losses = [], [], []
-        for i in range(self.n):
+        for i in ids:
             (self.client_opt[i], self.client_bn[i], self.streams[i], g, rep,
              loss) = self._client(self.params, self.client_opt[i],
                                   self.client_bn[i], self.streams[i],
@@ -293,12 +341,14 @@ class Reference:
             grads.append(g)
             reports.append(rep)
             losses.append(loss)
-        picks = self.select(np.asarray(jax.device_get(reports)))
+        picks = self.select(np.asarray(jax.device_get(reports)), ids)
         self.params, self.opt = self._global(
             self.params, self.opt, jnp.stack(grads),
-            jnp.asarray(picks, jnp.int32))
+            jnp.asarray(picks[ids], jnp.int32))
         self.round += 1
-        self.traj.losses.append(np.asarray(jax.device_get(losses)))
+        row = np.full(self.n, np.nan, np.float32)
+        row[ids] = jax.device_get(losses)
+        self.traj.losses.append(row)
         self.traj.picks.append(picks)
         if self.round % self.p["M"] == 0:
             new = cluster(self.freq, self.p["eps"], self.p["min_pts"])

@@ -93,10 +93,10 @@ def run(argv=None, *, require_chip: bool = True, t0: float = T0,
           f"phase: {json.dumps(r.phases)}", file=log)
     result = {"correct": False, "attempted": 0, "failed": 0, "metrics": {}}
     if args.trace:
-        summary, rounds, stall = r.traced()
+        summary, rounds, stall, active = r.traced()
         result["attempted"] = rounds
         result["metrics"] = harness.per_layer(cell, summary, rounds, stall,
-                                              device["kind"])
+                                              device["kind"], active)
         if summary:
             device.update(busy_s=summary["busy_s"],
                           window_s=summary["window_s"])
@@ -130,6 +130,7 @@ def run(argv=None, *, require_chip: bool = True, t0: float = T0,
     r.compiles.close()
 
     numbers = r.check()
+    print(f"check_s={r.check_s:.3f}", file=log)
     ok, checks = compare.judge(numbers, cell["limits"])
     result["correct"] = ok and result["failed"] == 0
     result["device"] = device

@@ -49,16 +49,19 @@ def retraces_of(engine) -> dict | None:
 
 def traced_planes(r: harness.Run) -> tuple:
     """The cell's ``trace_calls`` calls under the profiler: (planes with
-    scope paths, rounds, recluster wait, chunk programs built), the last
-    None for an engine without the counter."""
+    scope paths, rounds, recluster wait, chunk programs built,
+    participants summed over the rounds), the fourth None for an engine
+    without the counter."""
     wait0, built0 = r.engine.recluster_wait_s, retraces_of(r.engine)
-    rounds = 0
+    rounds = active = 0
     with tempfile.TemporaryDirectory(prefix="bench-scopes-") as d:
         jax.profiler.start_trace(d)
         try:
             with jax.profiler.TraceAnnotation("bench.window"):
                 for _ in range(r.mix["trace_calls"]):
-                    rounds += len(r._call().n_active)
+                    n_active = r._call().n_active
+                    rounds += len(n_active)
+                    active += sum(n_active)
         finally:
             jax.profiler.stop_trace()
         # the op_name metadata a TPU trace's op events lack
@@ -69,18 +72,18 @@ def traced_planes(r: harness.Run) -> tuple:
     built1 = retraces_of(r.engine)
     built = (None if built0 is None
              else {k: built1[k] - built0.get(k, 0) for k in built1})
-    return planes, rounds, r.engine.recluster_wait_s - wait0, built
+    return planes, rounds, r.engine.recluster_wait_s - wait0, built, active
 
 
 def report(cell: dict, planes: list, rounds: int, stall: float, built,
-           device_kind: str) -> dict:
+           device_kind: str, active: int | None = None) -> dict:
     """The window's readings: the benchmark's own reduction and per-layer
     metrics beside the scope and span readings."""
     summary = trace_reduce.reduce(scopes.plain(planes), "bench.window")
     by_name = scopes.reduce(planes, "bench.window")
     out = {"rounds": rounds, "retraces": built,
            "metrics": harness.per_layer(cell, summary, rounds, stall,
-                                        device_kind)}
+                                        device_kind, active)}
     if summary is None or by_name is None:
         return out
     scope_s = by_name["scope_s"]
@@ -118,11 +121,12 @@ def main(argv=None, *, require_chip: bool = True,
     r = harness.Run(cell, args.seed, T0)
     r.setup()
     r.compiles.on = True
-    planes, rounds, stall, built = traced_planes(r)
+    planes, rounds, stall, built, active = traced_planes(r)
     r.compiles.on = False
     out = {"workload": args.workload, "seed": args.seed, "device": device,
            "setup_s": r.setup_s, "compiles_in_window": len(r.compiles.names)}
-    out.update(report(cell, planes, rounds, stall, built, device["kind"]))
+    out.update(report(cell, planes, rounds, stall, built, device["kind"],
+                      active))
     r.compiles.close()
     r.engine.close()
     print(json.dumps(out), flush=True)

@@ -51,3 +51,28 @@ def tiny_bench(tmp: Path, limits_of: str = "mnist_mlp.paper_fig3") -> Path:
         m.pop("workloads", None)
     (tmp / "BENCHMARK.json").write_text(json.dumps(spec))
     return bench
+
+
+# the per-writer sample counts of LEAF's FEMNIST (arXiv 1812.01097, Table 1)
+FEMNIST_SIZES = {"law": "lognormal", "mean": 226.83, "std": 88.94,
+                 "source": "LEAF FEMNIST, arXiv 1812.01097 Table 1"}
+
+
+def participation_bench(tmp: Path, layout: str) -> Path:
+    """``tiny_bench``'s layout with its cell's federation replaced: N = 16
+    two-label clients with FEMNIST's shard sizes, UniformM m = 4 a round,
+    the given age layout, M = 4 and an evaluation every 4 rounds, so that
+    the 8 checked rounds hold two reclusters. Limits as ``tiny_bench``'s."""
+    bench = tiny_bench(tmp)
+    path = bench / "configs" / "mnist_mlp.json"
+    cfg = load(path)
+    cfg["clients"] = [[2 * (c % 5), 2 * (c % 5) + 1] for c in range(16)]
+    cfg["dataset"]["shard_sizes"] = FEMNIST_SIZES
+    del cfg["dataset"]["n_train"]
+    cfg["protocol"].update(schedule="uniform", participation_m=4,
+                           age_layout=layout)
+    path.write_text(json.dumps(cfg))
+    (bench / "traffic" / "tiny.json").write_text(json.dumps(
+        {"eval_every": 4, "overrides": {}, "warm_calls": 2, "check_calls": 2,
+         "trace_calls": 1}))
+    return bench
